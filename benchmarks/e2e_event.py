@@ -281,7 +281,7 @@ def run_packed() -> list[str]:
     platform = jax.default_backend()
     tpu = platform == "tpu"
     csr = "pallas-csr" if tpu else "pallas-csr-interpret"
-    pcsr = "packed-csr" if tpu else "packed-csr-interpret"
+    pcsr = "packed-csr-interpret"   # the packed family is CPU-only
 
     def f32_scope():
         import contextlib
@@ -389,12 +389,14 @@ def main() -> None:
     if not args.packed:
         print("\n".join(run()))
         return
+    if not dispatch.packed_kernels_available():
+        raise SystemExit(f"--packed: no packed-csr kernels on "
+                         f"{jax.default_backend()}")
     from .sparsity_sweep import run_packed as run_packed_ops
     rows = run_packed_ops() + run_packed()
     print("\n".join(rows))
     if args.json:
-        pcsr = ("packed-csr" if jax.default_backend() == "tpu"
-                else "packed-csr-interpret")
+        pcsr = "packed-csr-interpret"
         with dispatch.use_backend(pcsr, op="spike_matmul"), \
                 dispatch.use_backend(pcsr, op="apec_matmul"), \
                 dispatch.use_backend(pcsr, op="econv"):

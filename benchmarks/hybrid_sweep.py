@@ -238,8 +238,8 @@ def run_mesh(n_shards: int = MESH_SHARDS) -> list[str]:
     if len(jax.devices()) < n_shards:
         raise RuntimeError(
             f"mesh sweep needs {n_shards} devices, have {len(jax.devices())}"
-            " (run via the suite entry, which re-launches with host"
-            " devices forced)")
+            " (on a CPU host set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_shards})")
     mesh = make_mesh((n_shards, 1), ("data", "model"))
     w = jax.random.normal(jax.random.PRNGKey(0), (K_MESH, N_MESH),
                           jnp.float32) * 0.05
@@ -305,38 +305,12 @@ def run_mesh(n_shards: int = MESH_SHARDS) -> list[str]:
     return rows
 
 
-def _mesh_subprocess_rows(n_shards: int = MESH_SHARDS) -> list[str]:
-    """Re-launch with forced host devices (the XLA device-count flag is
-    process-global and must precede the jax import)."""
-    import os
-    import subprocess
-    import sys
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_shards} "
-                        "--xla_backend_optimization_level=0")
-    env.setdefault("PYTHONPATH", "src")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.hybrid_sweep", "--mesh",
-         "--shards", str(n_shards)],
-        capture_output=True, text=True, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"hybrid mesh subprocess failed:\n{proc.stderr}")
-    return [ln for ln in proc.stdout.splitlines() if ln.strip()]
-
-
-def run_mesh_rows() -> list[str]:
-    if len(jax.devices()) >= MESH_SHARDS:
-        return run_mesh()
-    return _mesh_subprocess_rows()
-
-
 def main() -> None:
     import argparse
     import json
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", action="store_true",
-                    help="mesh rows only (expects forced host devices)")
+                    help="mesh rows only")
     ap.add_argument("--shards", type=int, default=MESH_SHARDS)
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write BENCH_PR6-schema JSON (single-device + "
@@ -346,7 +320,7 @@ def main() -> None:
         print("\n".join(run_mesh(args.shards)))
         return
     rows = run()
-    mesh_rows = run_mesh_rows()
+    mesh_rows = run_mesh()
     print("\n".join(rows + mesh_rows))
     if args.json:
         with dispatch.use_hybrid():

@@ -60,13 +60,13 @@ def _suites():
         ("e2e_event", e2e_event.run),
         # whole-network packed pipeline vs f32 CSR + bytes-moved ledger
         ("e2e_packed", e2e_event.run_packed),
-        # sharded-vs-single CSR columns (8-way host mesh; re-launches
-        # itself with forced host devices when this process has fewer)
+        # sharded-vs-single CSR columns (8-way mesh on this process's
+        # devices; on a CPU host force them with XLA_FLAGS)
         ("sparsity_mesh", sparsity_sweep.run_mesh_rows),
         # density-adaptive hybrid dispatch vs the two static pins
         # (single-device model stacks + 8-way mesh rows)
         ("hybrid", hybrid_sweep.run),
-        ("hybrid_mesh", hybrid_sweep.run_mesh_rows),
+        ("hybrid_mesh", hybrid_sweep.run_mesh),
         # EXSPIKE_GUARD audit/repair vs off (dense + packed payloads)
         ("guard", guard_overhead.run),
         # continuous-batching scheduler: trace-replay p50/p99 latency +
@@ -87,6 +87,8 @@ def main() -> None:
                          "requested override, the RESOLVED per-op backends "
                          "(post-fallback), and the rows.")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     suites = _suites()
     if args.only:

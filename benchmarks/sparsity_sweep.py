@@ -381,7 +381,8 @@ def run_mesh(n_shards: int = MESH_SHARDS) -> list[str]:
     if len(jax.devices()) < n_shards:
         raise RuntimeError(
             f"mesh sweep needs {n_shards} devices, have {len(jax.devices())}"
-            " (run via --mesh, which re-launches with host devices forced)")
+            " (on a CPU host set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_shards})")
     mesh = make_mesh((n_shards, 1), ("data", "model"))
     csr = "pallas-csr" if platform == "tpu" else "pallas-csr-interpret"
     rows = []
@@ -469,7 +470,8 @@ def run_mesh_rebalance(n_shards: int = MESH_SHARDS) -> list[str]:
     if len(jax.devices()) < n_shards:
         raise RuntimeError(
             f"rebalance sweep needs {n_shards} devices, have "
-            f"{len(jax.devices())} (run via --mesh --rebalance)")
+            f"{len(jax.devices())} (on a CPU host set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_shards})")
     mesh = make_mesh((n_shards, 1), ("data", "model"))
     csr = "pallas-csr" if platform == "tpu" else "pallas-csr-interpret"
     rows = []
@@ -516,36 +518,11 @@ def run_mesh_rebalance(n_shards: int = MESH_SHARDS) -> list[str]:
     return rows
 
 
-def _mesh_subprocess_rows(n_shards: int = MESH_SHARDS,
-                          rebalance: bool = False) -> list[str]:
-    """Re-launch this module with `n_shards` forced host devices (the XLA
-    device-count flag is process-global and must precede the jax import)
-    and collect its CSV rows. `rebalance` adds the static-vs-rebalanced
-    hotspot rows (`run_mesh_rebalance`)."""
-    import os
-    import subprocess
-    import sys
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_shards} "
-                        "--xla_backend_optimization_level=0")
-    env.setdefault("PYTHONPATH", "src")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.sparsity_sweep", "--mesh",
-         "--shards", str(n_shards)]
-        + (["--rebalance"] if rebalance else []),
-        capture_output=True, text=True, env=env)
-    if proc.returncode != 0:
-        raise RuntimeError(f"mesh sweep subprocess failed:\n{proc.stderr}")
-    return [ln for ln in proc.stdout.splitlines() if ln.strip()]
-
-
 def run_mesh_rows() -> list[str]:
-    """Suite entry for benchmarks.run: in-process when the host already
-    exposes enough devices, else via the forced-device subprocess."""
-    if len(jax.devices()) >= MESH_SHARDS:
-        return run_mesh()
-    return _mesh_subprocess_rows()
+    """Suite entry for benchmarks.run: the mesh rows on this process's
+    own devices (on a CPU host, force them with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``)."""
+    return run_mesh()
 
 
 def main() -> None:
@@ -568,17 +545,11 @@ def main() -> None:
                          "(attribution), and the rows")
     ap.add_argument("--pr10", default=None, metavar="PATH",
                     help="write BENCH_PR10 JSON: pipelined paired rows "
-                         "(in-process) plus mesh + rebalance rows (forced-"
-                         "device subprocess when needed)")
+                         "plus mesh + rebalance rows")
     args = ap.parse_args()
     if args.pr10:
-        pipe_rows = run_pipelined()
-        if len(jax.devices()) >= args.shards:
-            mesh_rows = run_mesh(args.shards) + run_mesh_rebalance(
-                args.shards)
-        else:
-            mesh_rows = _mesh_subprocess_rows(args.shards, rebalance=True)
-        rows = pipe_rows + mesh_rows
+        rows = (run_pipelined() + run_mesh(args.shards)
+                + run_mesh_rebalance(args.shards))
         print("\n".join(rows))
         with open(args.pr10, "w") as f:
             json.dump({"mesh": {"shards": args.shards,
@@ -599,12 +570,9 @@ def main() -> None:
     if not args.mesh:
         print("\n".join(run()))
         return
-    if len(jax.devices()) < args.shards:
-        rows = _mesh_subprocess_rows(args.shards, rebalance=args.rebalance)
-    else:
-        rows = run_mesh(args.shards)
-        if args.rebalance:
-            rows += run_mesh_rebalance(args.shards)
+    rows = run_mesh(args.shards)
+    if args.rebalance:
+        rows += run_mesh_rebalance(args.shards)
     print("\n".join(rows))
     if args.json:
         from repro.kernels import dispatch

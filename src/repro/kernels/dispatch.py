@@ -142,6 +142,12 @@ class Backend:
     # also accepts dense operands (packs internally), which is how the
     # parity harness covers it with dense example inputs.
     payload: Tuple[str, ...] = ("dense",)
+    # A compiled (Mosaic) kernel that GSPMD cannot partition but that can
+    # run per data shard, split on `SHARD_BATCH_AXIS` of every array
+    # operand: a step traced under `use_mesh(mesh, split_kernels=True)`
+    # runs it in a `shard_map` (`_gspmd_shard_wrap`). False leaves the
+    # call to the partitioner (jnp code, interpret-mode kernels).
+    per_data_shard: bool = False
 
     def unsupported_reason(self, *args, **kwargs) -> Optional[str]:
         platform = jax.default_backend()
@@ -287,7 +293,8 @@ def _matmul_bwd(res, kwargs, g):
 
 def register(op: str, name: str, *, platforms=ALL_PLATFORMS, priority=0,
              auto=True, supports=None, differentiable=False, vjp=None,
-             fallback=None, mesh_aware=False, payload=("dense",)):
+             fallback=None, mesh_aware=False, payload=("dense",),
+             per_data_shard=False):
     """Decorator: register `fn` as backend `name` for `op`.
 
     Gradient contract: pass ``differentiable=True`` when `jax.grad`
@@ -310,6 +317,9 @@ def register(op: str, name: str, *, platforms=ALL_PLATFORMS, priority=0,
     ``payload``: payload capability (see `Backend.payload`) — the default
     ``("dense",)`` keeps the backend off packed-payload calls; declare
     ``("packed",)`` for backends consuming uint32 spike words natively.
+
+    ``per_data_shard``: a compiled kernel that runs per data shard under
+    a GSPMD mesh (see `Backend.per_data_shard`).
     """
     def deco(fn):
         if op not in _REGISTRY:
@@ -320,7 +330,7 @@ def register(op: str, name: str, *, platforms=ALL_PLATFORMS, priority=0,
             priority=priority, auto=auto, supports=supports,
             differentiable=differentiable or vjp is not None,
             fallback=fallback, mesh_aware=mesh_aware,
-            payload=tuple(payload))
+            payload=tuple(payload), per_data_shard=per_data_shard)
         return fn
     return deco
 
@@ -351,6 +361,15 @@ def differentiable_backend_names(op: str) -> Tuple[str, ...]:
     """Backends of `op` declaring the gradient contract (grad-parity set)."""
     return tuple(n for n, b in _REGISTRY[op].backends.items()
                  if b.differentiable)
+
+
+def packed_kernels_available() -> bool:
+    """Whether this platform registers packed-payload matmul kernels.
+    Packed emission (`SpikingConfig.packed`) is refused where it is not:
+    every consumer would otherwise densify through the unpack shim."""
+    platform = jax.default_backend()
+    return any("packed" in b.payload and platform in b.platforms
+               for b in _REGISTRY["spike_matmul"].backends.values())
 
 
 # -------------------------------------------------------------- overrides
@@ -418,21 +437,32 @@ def use_hybrid(op: Optional[str] = None):
 
 # ------------------------------------------------------------ mesh context
 _MESH: list = []   # stack of ambient meshes for trace-time resolution
+_SPLIT: list = []  # parallel stack: split per-data-shard kernels?
+# Batch axis of the `per_data_shard` kernels' array operands (axis 0 is
+# time for lif_scan and causal_sdsa).
+SHARD_BATCH_AXIS = 1
 
 
 @contextlib.contextmanager
-def use_mesh(mesh):
+def use_mesh(mesh, split_kernels: bool = False):
     """Ambient mesh for resolution: while active, `resolve`/`dispatch`
     treat every call as executing per data shard (capability checks run on
     per-shard shapes, non-mesh-aware backends are skipped). Push it around
     jit tracing of sharded step functions — resolution is trace-time, so
     the context must be live when the jit cache misses, not per step.
-    `mesh` may be a jax Mesh/AbstractMesh or a plain int shard count."""
+    `mesh` may be a jax Mesh/AbstractMesh or a plain int shard count.
+
+    ``split_kernels``: the traced step's operands live on the concrete
+    `mesh` (the sharded train step), so `per_data_shard` kernels run in a
+    shard_map over its data axes. Off, they run whole: a step on unplaced
+    operands (the serve steps) must not be moved onto the mesh."""
     _MESH.append(mesh)
+    _SPLIT.append(split_kernels)
     try:
         yield
     finally:
         _MESH.pop()
+        _SPLIT.pop()
 
 
 def ambient_mesh():
@@ -967,6 +997,38 @@ def _unpack_shim(be: Backend, packed_k: int) -> Backend:
     return dataclasses.replace(be, fn=fn, name=f"{be.name}+unpack")
 
 
+def _gspmd_shard_wrap(be: Backend, args) -> Backend:
+    """Run a compiled kernel per data shard inside a step traced under
+    `use_mesh(mesh, split_kernels=True)`: Mosaic kernels cannot be
+    partitioned automatically, so the call goes into a `shard_map` over
+    the mesh's batch axes, splitting `SHARD_BATCH_AXIS` of every
+    positional (array) operand and of the output; keyword arguments are
+    static. A batch the shard count does not divide runs whole on every
+    device (replicated specs). No-op unless split_kernels is asked for
+    under a concrete Mesh with data shards, and inside a shard_map that
+    already made those axes manual."""
+    mesh = ambient_mesh()
+    n_shards = data_shard_count(mesh)
+    if not (_SPLIT and _SPLIT[-1]) \
+            or not isinstance(mesh, jax.sharding.Mesh) or n_shards < 2:
+        return be
+    axes = tuple(a for a in ("pod", "data") if mesh.shape.get(a, 1) > 1)
+    if set(axes) & set(jax.sharding.get_abstract_mesh().manual_axes):
+        return be
+    if all(a.shape[SHARD_BATCH_AXIS] % n_shards == 0 for a in args):
+        spec = jax.sharding.PartitionSpec(
+            *([None] * SHARD_BATCH_AXIS), axes)
+    else:
+        spec = jax.sharding.PartitionSpec()
+    inner = be.fn
+
+    def fn(*a, **kw):
+        return jax.shard_map(lambda *x: inner(*x, **kw), mesh=mesh,
+                             in_specs=(spec,) * len(a), out_specs=spec,
+                             check_vma=False)(*a)
+    return dataclasses.replace(be, fn=fn)
+
+
 def _resolve_impl(op: str, *args, mesh=None,
                   **kwargs) -> Tuple[Backend, str]:
     be, attribution = _resolve_payload_blind(op, *args, mesh=mesh, **kwargs)
@@ -980,6 +1042,8 @@ def _resolve_impl(op: str, *args, mesh=None,
         shim = _unpack_shim(be, packed_k)
         attribution = shim.name + attribution[len(be.name):]
         be = shim
+    if be.per_data_shard:
+        be = _gspmd_shard_wrap(be, args)
     # Guard policy (audit/repair) wraps OUTERMOST so the audit sees the
     # payload exactly as carried (packed words before any unpack shim).
     # Off (the default) adds nothing — attributions stay byte-identical.
@@ -1221,7 +1285,7 @@ def _lif_pallas(x, *, decay=0.5, v_th=1.0, soft_reset=True,
 register("lif_scan", "pallas-interpret", platforms=("cpu",), priority=1,
          auto=False, differentiable=True, mesh_aware=True)(_lif_pallas)
 register("lif_scan", "pallas", platforms=("tpu",), priority=20,
-         differentiable=True, mesh_aware=True)(_lif_pallas)
+         differentiable=True, mesh_aware=True, per_data_shard=True)(_lif_pallas)
 
 
 # --------------------------------------------------------- lif_scan_occ
@@ -1364,6 +1428,12 @@ register("spike_matmul", "pallas-csr", platforms=("tpu",), priority=25,
          mesh_aware=_csr_shard_gate)(_spike_matmul_csr)
 
 
+# The packed-csr family (spike_matmul, apec_matmul, econv) is registered
+# for the CPU interpreter only. Its word operand arrives in
+# (block_m, block_k/32) = (128, 4) uint32 blocks, which Mosaic refuses
+# (a block's last two dims must divide by (8, 128) or span the array),
+# so there is no TPU registration; `packed_kernels_available()` is False
+# there and packed emission raises instead of densifying.
 def _spike_matmul_packed(s, w, occupancy=None, packed_k=None):
     # packed-csr: the spike operand stays uint32 words end to end; each
     # occupied tile unpacks VMEM-resident inside the CSR grid step (see
@@ -1377,10 +1447,6 @@ def _spike_matmul_packed(s, w, occupancy=None, packed_k=None):
 register("spike_matmul", "packed-csr-interpret", platforms=("cpu",),
          priority=3, auto=False, fallback="pallas-csr-interpret",
          vjp=_matmul_bwd, mesh_aware=_csr_shard_gate,
-         payload=("packed",))(_spike_matmul_packed)
-register("spike_matmul", "packed-csr", platforms=("tpu",), priority=30,
-         fallback="pallas-csr", vjp=_matmul_bwd,
-         mesh_aware=_csr_shard_gate,
          payload=("packed",))(_spike_matmul_packed)
 
 
@@ -1413,10 +1479,6 @@ def _spike_matmul_packed_pipe(s, w, occupancy=None, packed_k=None):
 register("spike_matmul", "packed-csr-pipe-interpret", platforms=("cpu",),
          priority=6, auto=False, fallback="packed-csr-interpret",
          vjp=_matmul_bwd, mesh_aware=_csr_shard_gate,
-         payload=("packed",))(_spike_matmul_packed_pipe)
-register("spike_matmul", "packed-csr-pipe", platforms=("tpu",), priority=31,
-         fallback="packed-csr", vjp=_matmul_bwd,
-         mesh_aware=_csr_shard_gate,
          payload=("packed",))(_spike_matmul_packed_pipe)
 
 
@@ -1513,10 +1575,6 @@ register("apec_matmul", "packed-csr-interpret", platforms=("cpu",),
          fallback="pallas-csr-interpret", vjp=_matmul_bwd,
          mesh_aware=_csr_shard_gate,
          payload=("packed",))(_apec_matmul_packed)
-register("apec_matmul", "packed-csr", platforms=("tpu",), priority=30,
-         supports=_apec_csr_supports, fallback="pallas-csr",
-         vjp=_matmul_bwd, mesh_aware=_csr_shard_gate,
-         payload=("packed",))(_apec_matmul_packed)
 
 
 def _apec_matmul_csr_pipe(s, w, *, g=2, occupancy=None):
@@ -1534,22 +1592,6 @@ register("apec_matmul", "pallas-csr-pipe-interpret", platforms=("cpu",),
 register("apec_matmul", "pallas-csr-pipe", platforms=("tpu",), priority=26,
          supports=_apec_csr_supports, fallback="pallas-csr",
          vjp=_matmul_bwd, mesh_aware=_csr_shard_gate)(_apec_matmul_csr_pipe)
-
-
-def _apec_matmul_packed_pipe(s, w, *, g=2, occupancy=None, packed_k=None):
-    from repro.kernels import ops
-    return ops.apec_matmul_packed(s, w, g=g, packed_k=packed_k,
-                                  occupancy=occupancy, pipeline=True)
-
-
-# TPU-only: the packed-apec pipe kernel is the packed-spike pipe kernel
-# plus the (CPU-covered) fused-APEC pipe epilogue; a cpu-interpret twin
-# would re-test that composition at real wall-clock cost in the tier-1
-# gate for no new coverage.
-register("apec_matmul", "packed-csr-pipe", platforms=("tpu",), priority=31,
-         supports=_apec_csr_supports, fallback="packed-csr",
-         vjp=_matmul_bwd, mesh_aware=_csr_shard_gate,
-         payload=("packed",))(_apec_matmul_packed_pipe)
 
 
 # ------------------------------------------------------------------ sdsa
@@ -1655,8 +1697,8 @@ register("causal_sdsa", "pallas-interpret", platforms=("cpu",), priority=1,
          auto=False, supports=_causal_or_only, vjp="ref",
          mesh_aware=True)(_causal_sdsa_pallas)
 register("causal_sdsa", "pallas", platforms=("tpu",), priority=20,
-         supports=_causal_or_only, vjp="ref",
-         mesh_aware=True)(_causal_sdsa_pallas)
+         supports=_causal_or_only, vjp="ref", mesh_aware=True,
+         per_data_shard=True)(_causal_sdsa_pallas)
 
 
 # ----------------------------------------------------------------- econv
@@ -1775,9 +1817,6 @@ register("econv", "packed-csr-interpret", platforms=("cpu",), priority=3,
          auto=False, supports=_econv_packed_supports,
          fallback="pallas-csr-interpret", vjp="ref",
          mesh_aware=_csr_shard_gate, payload=("packed",))(_econv_packed_csr)
-register("econv", "packed-csr", platforms=("tpu",), priority=30,
-         supports=_econv_packed_supports, fallback="pallas-csr", vjp="ref",
-         mesh_aware=_csr_shard_gate, payload=("packed",))(_econv_packed_csr)
 
 
 def _econv_csr_pipe(s, w, *, stride=1, padding="SAME", occupancy=None):
@@ -1795,23 +1834,6 @@ register("econv", "pallas-csr-pipe-interpret", platforms=("cpu",),
 register("econv", "pallas-csr-pipe", platforms=("tpu",), priority=26,
          fallback="pallas-csr", vjp="ref",
          mesh_aware=_csr_shard_gate)(_econv_csr_pipe)
-
-
-def _econv_packed_csr_pipe(s, w, *, stride=1, padding="SAME",
-                           occupancy=None, packed_k=None):
-    from repro.kernels import ops
-    return ops.econv_packed(s, w, stride=stride, padding=padding,
-                            packed_k=packed_k, occupancy=occupancy,
-                            pipeline=True)
-
-
-# TPU-only for the same reason as apec's packed pipe twin (word-domain
-# im2col is CPU-covered by packed-csr-interpret; the pipelined matmul
-# underneath is CPU-covered by packed-csr-pipe-interpret).
-register("econv", "packed-csr-pipe", platforms=("tpu",), priority=31,
-         supports=_econv_packed_supports, fallback="packed-csr", vjp="ref",
-         mesh_aware=_csr_shard_gate,
-         payload=("packed",))(_econv_packed_csr_pipe)
 
 
 # ----------------------------------------------------------------- tconv

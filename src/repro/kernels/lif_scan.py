@@ -138,8 +138,10 @@ def _lif_bwd_kernel(vres_ref, g_ref, dx_ref, u_ref, *, t_steps: int,
     jax.lax.fori_loop(0, t_steps, body, ())
 
 
-def _lif_fwd_pallas(x, *, decay, v_th, soft_reset, block_m, block_n):
-    interpret = jax.default_backend() == "cpu"
+def _lif_fwd_pallas(x, *, decay, v_th, soft_reset, block_m, block_n,
+                    interpret=None):
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
     t_steps, m, n = x.shape
     if m % block_m or n % block_n:
         raise ValueError(f"(M,N)=({m},{n}) must tile by ({block_m},{block_n})")
@@ -160,8 +162,9 @@ def _lif_fwd_pallas(x, *, decay, v_th, soft_reset, block_m, block_n):
 
 
 def _lif_bwd_pallas(vres, g, *, decay, v_th, soft_reset, surrogate_alpha,
-                    block_m, block_n):
-    interpret = jax.default_backend() == "cpu"
+                    block_m, block_n, interpret=None):
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
     t_steps, m, n = vres.shape
     kernel = functools.partial(
         _lif_bwd_kernel, t_steps=t_steps, decay=decay, v_th=v_th,
@@ -188,8 +191,28 @@ def _lif_bwd_pallas(vres, g, *, decay, v_th, soft_reset, surrogate_alpha,
 # and aggregated to the consumers' (128, 128) matmul tiling outside the
 # kernel by `kernels.ops.lif_occ` (a reduction over the tiny count map,
 # not the spike tensor).
+#
+# TPU layout: the counts are ONE whole-array SMEM table, flat
+# (T * M/bm * N/bn,) int32, each grid step writing its own slots by
+# program id. A per-step (1, 1) count block breaks Mosaic's (8, 128)
+# block rule; a lane-dense VMEM count block would cost an HBM write per
+# tile. SMEM holds 1 MiB, so `_occ_call` splits the rows over several
+# calls whenever one table would pass `_COUNT_WORDS`.
+_COUNT_WORDS = 64 * 1024
+
+
+def _count_slots():
+    """t -> flat SMEM slot of this grid step's (t, row-chunk, lane-tile)
+    count. Grid ids are read here, at kernel top level: the interpreter
+    cannot lower `program_id` inside the scan's loop body."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    mb, nb = pl.num_programs(0), pl.num_programs(1)
+    return lambda t: (t * mb + i) * nb + j
+
+
 def _lif_occ_kernel(x_ref, s_ref, cnt_ref, v_ref, *, t_steps: int,
                     decay: float, v_th: float, soft_reset: bool):
+    count_slot = _count_slots()
     v_ref[...] = jnp.zeros_like(v_ref)
 
     def body(t, _):
@@ -200,7 +223,7 @@ def _lif_occ_kernel(x_ref, s_ref, cnt_ref, v_ref, *, t_steps: int,
         else:
             v_ref[...] = v * (1.0 - s)
         s_ref[t] = s.astype(s_ref.dtype)
-        cnt_ref[t, 0, 0] = jnp.sum(s.astype(jnp.int32))   # tile popcount
+        cnt_ref[count_slot(t)] = jnp.sum(s.astype(jnp.int32))  # popcount
         return ()
 
     jax.lax.fori_loop(0, t_steps, body, ())
@@ -211,6 +234,7 @@ def _lif_occ_fwd_kernel(x_ref, s_ref, cnt_ref, vres_ref, v_ref, *,
                         soft_reset: bool):
     """Autodiff forward: spikes + per-tile counts + pre-reset membrane
     residuals (what the surrogate backward consumes)."""
+    count_slot = _count_slots()
     v_ref[...] = jnp.zeros_like(v_ref)
 
     def body(t, _):
@@ -222,41 +246,68 @@ def _lif_occ_fwd_kernel(x_ref, s_ref, cnt_ref, vres_ref, v_ref, *,
         else:
             v_ref[...] = v * (1.0 - s)
         s_ref[t] = s.astype(s_ref.dtype)
-        cnt_ref[t, 0, 0] = jnp.sum(s.astype(jnp.int32))
+        cnt_ref[count_slot(t)] = jnp.sum(s.astype(jnp.int32))
         return ()
 
     jax.lax.fori_loop(0, t_steps, body, ())
 
 
+def _occ_call(kernel, x, payload, *, block_m, block_n, interpret):
+    """Run a fused fire+count kernel over x (T, M, N).
+
+    `payload`: ((dtype, width_div), ...), one per tensor output: block
+    (T, block_m, block_n // width_div) of a (T, M, N // width_div) array.
+    Returns the first payload output, the counts (T, M/bm, N/bn), then
+    the other payload outputs — the kernel's output order — splitting the
+    rows across calls when one SMEM count table would pass
+    `_COUNT_WORDS`."""
+    t_steps, m, n = x.shape
+    nb = n // block_n
+    rows_per_call = max(1, _COUNT_WORDS // (t_steps * nb)) * block_m
+    if m > rows_per_call:
+        parts = [_occ_call(kernel, x[:, a:a + rows_per_call], payload,
+                           block_m=block_m, block_n=block_n,
+                           interpret=interpret)
+                 for a in range(0, m, rows_per_call)]
+        return tuple(jnp.concatenate(o, axis=1) for o in zip(*parts))
+    mb = m // block_m
+    specs = tuple(
+        pl.BlockSpec((t_steps, block_m, block_n // div),
+                     lambda i, j: (0, i, j)) for _, div in payload)
+    shapes = tuple(jax.ShapeDtypeStruct((t_steps, m, n // div), dt)
+                   for dt, div in payload)
+    outs = pl.pallas_call(
+        kernel,
+        grid=(mb, nb),
+        in_specs=[pl.BlockSpec((t_steps, block_m, block_n),
+                               lambda i, j: (0, i, j))],
+        out_specs=specs[:1] + (pl.BlockSpec(memory_space=pltpu.SMEM),)
+        + specs[1:],
+        out_shape=shapes[:1]
+        + (jax.ShapeDtypeStruct((t_steps * mb * nb,), jnp.int32),)
+        + shapes[1:],
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        interpret=interpret,
+    )(x)
+    return (outs[0], outs[1].reshape(t_steps, mb, nb), *outs[2:])
+
+
 def _lif_occ_pallas(x, *, decay, v_th, soft_reset, block_m, block_n,
-                    emit_vres: bool):
+                    emit_vres: bool, interpret: bool | None = None):
     """x: (T, M, N) -> (spikes (T, M, N), counts (T, M/bm, N/bn) int32
     [, vres (T, M, N) f32]). Counts live in SMEM: one scalar per
     (t, row-chunk, lane-tile), written while the spike tile is resident."""
-    interpret = jax.default_backend() == "cpu"
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
     t_steps, m, n = x.shape
     if m % block_m or n % block_n:
         raise ValueError(f"(M,N)=({m},{n}) must tile by ({block_m},{block_n})")
     kernel = functools.partial(
         _lif_occ_fwd_kernel if emit_vres else _lif_occ_kernel,
         t_steps=t_steps, decay=decay, v_th=v_th, soft_reset=soft_reset)
-    spec = pl.BlockSpec((t_steps, block_m, block_n), lambda i, j: (0, i, j))
-    cnt_spec = pl.BlockSpec((t_steps, 1, 1), lambda i, j: (0, i, j),
-                            memory_space=pltpu.SMEM)
-    cnt_shape = jax.ShapeDtypeStruct(
-        (t_steps, m // block_m, n // block_n), jnp.int32)
-    out_specs = (spec, cnt_spec) + ((spec,) if emit_vres else ())
-    out_shape = (jax.ShapeDtypeStruct(x.shape, x.dtype), cnt_shape) \
-        + ((jax.ShapeDtypeStruct(x.shape, jnp.float32),) if emit_vres else ())
-    return pl.pallas_call(
-        kernel,
-        grid=(m // block_m, n // block_n),
-        in_specs=[spec],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
-    )(x)
+    payload = ((x.dtype, 1),) + (((jnp.float32, 1),) if emit_vres else ())
+    return _occ_call(kernel, x, payload, block_m=block_m, block_n=block_n,
+                     interpret=interpret)
 
 
 def _lif_occ_packed_kernel(x_ref, p_ref, cnt_ref, v_ref, *, t_steps: int,
@@ -268,11 +319,12 @@ def _lif_occ_packed_kernel(x_ref, p_ref, cnt_ref, v_ref, *, t_steps: int,
     packing, and the f32 spike tile never reaches HBM at all (32x less
     spike traffic out of the producer).
 
-    TPU layout note: the packed store's lane dim is block_n/32 (=4 at the
-    default 128); on real hardware a sublane-transposed store or an
-    8-word-wide block (block_n=256+) may lay out better — interpret mode
-    (all CI here) is layout-agnostic, so this keeps the canonical form.
+    Interpret mode only: the (T, block_m, block_n/32) word block (4 lanes
+    at the default 128) breaks Mosaic's (8, 128) block rule, so no TPU
+    backend reaches this kernel (see the packed family in
+    `kernels.dispatch`).
     """
+    count_slot = _count_slots()
     v_ref[...] = jnp.zeros_like(v_ref)
     weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
 
@@ -287,7 +339,7 @@ def _lif_occ_packed_kernel(x_ref, p_ref, cnt_ref, v_ref, *, t_steps: int,
         bits = s.reshape(bm, bn // 32, 32).astype(jnp.uint32)
         words = jnp.sum(bits * weights, axis=-1, dtype=jnp.uint32)
         p_ref[t] = words
-        cnt_ref[t, 0, 0] = jnp.sum(
+        cnt_ref[count_slot(t)] = jnp.sum(
             jax.lax.population_count(words).astype(jnp.int32))
         return ()
 
@@ -296,7 +348,8 @@ def _lif_occ_packed_kernel(x_ref, p_ref, cnt_ref, v_ref, *, t_steps: int,
 
 def lif_scan_occ_packed_pallas(x, *, decay: float = 0.5, v_th: float = 1.0,
                                soft_reset: bool = True, block_m: int = 8,
-                               block_n: int = 128):
+                               block_n: int = 128,
+                               interpret: bool | None = None):
     """Fused packed emission: x (T, M, N) -> (packed words
     (T, M, N/32) uint32, counts (T, M/bm, N/bn) int32).
 
@@ -306,7 +359,8 @@ def lif_scan_occ_packed_pallas(x, *, decay: float = 0.5, v_th: float = 1.0,
     emission and pack nothing). N must tile by block_n (>= and a multiple
     of 32), which the `ops.lif_occ` wrapper's 128-lane padding guarantees.
     """
-    interpret = jax.default_backend() == "cpu"
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
     x = jax.lax.stop_gradient(x)
     t_steps, m, n = x.shape
     if m % block_m or n % block_n or block_n % 32:
@@ -315,29 +369,16 @@ def lif_scan_occ_packed_pallas(x, *, decay: float = 0.5, v_th: float = 1.0,
     kernel = functools.partial(
         _lif_occ_packed_kernel, t_steps=t_steps, decay=decay, v_th=v_th,
         soft_reset=soft_reset)
-    spec = pl.BlockSpec((t_steps, block_m, block_n), lambda i, j: (0, i, j))
-    p_spec = pl.BlockSpec((t_steps, block_m, block_n // 32),
-                          lambda i, j: (0, i, j))
-    cnt_spec = pl.BlockSpec((t_steps, 1, 1), lambda i, j: (0, i, j),
-                            memory_space=pltpu.SMEM)
-    return pl.pallas_call(
-        kernel,
-        grid=(m // block_m, n // block_n),
-        in_specs=[spec],
-        out_specs=(p_spec, cnt_spec),
-        out_shape=(jax.ShapeDtypeStruct((t_steps, m, n // 32), jnp.uint32),
-                   jax.ShapeDtypeStruct(
-                       (t_steps, m // block_m, n // block_n), jnp.int32)),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
-    )(x)
+    return _occ_call(kernel, x, ((jnp.uint32, 32),), block_m=block_m,
+                     block_n=block_n, interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
 def lif_scan_occ_pallas_sg(x, decay: float = 0.5, v_th: float = 1.0,
                            soft_reset: bool = True,
                            surrogate_alpha: float = 2.0,
-                           block_m: int = 8, block_n: int = 128):
+                           block_m: int = 8, block_n: int = 128,
+                           interpret: bool | None = None):
     """Differentiable fused LIF with occupancy emission.
 
     x: (T, M, N) drive -> (spikes (T, M, N), counts (T, M/bm, N/bn)).
@@ -347,34 +388,37 @@ def lif_scan_occ_pallas_sg(x, decay: float = 0.5, v_th: float = 1.0,
     surrogate kernel as `lif_scan_pallas_sg`.
     """
     return _lif_occ_pallas(x, decay=decay, v_th=v_th, soft_reset=soft_reset,
-                           block_m=block_m, block_n=block_n, emit_vres=False)
+                           block_m=block_m, block_n=block_n, emit_vres=False,
+                           interpret=interpret)
 
 
 def _occ_sg_fwd(x, decay, v_th, soft_reset, surrogate_alpha, block_m,
-                block_n):
+                block_n, interpret):
     s, cnt, vres = _lif_occ_pallas(
         x, decay=decay, v_th=v_th, soft_reset=soft_reset, block_m=block_m,
-        block_n=block_n, emit_vres=True)
+        block_n=block_n, emit_vres=True, interpret=interpret)
     return (s, cnt), vres
 
 
 def _occ_sg_bwd(decay, v_th, soft_reset, surrogate_alpha, block_m, block_n,
-                vres, g):
+                interpret, vres, g):
     gs, _g_cnt = g          # occupancy aux carries no gradient
     dx = _lif_bwd_pallas(vres, gs, decay=decay, v_th=v_th,
                          soft_reset=soft_reset,
                          surrogate_alpha=surrogate_alpha,
-                         block_m=block_m, block_n=block_n)
+                         block_m=block_m, block_n=block_n,
+                         interpret=interpret)
     return (dx,)
 
 
 lif_scan_occ_pallas_sg.defvjp(_occ_sg_fwd, _occ_sg_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
 def lif_scan_pallas_sg(x, decay: float = 0.5, v_th: float = 1.0,
                        soft_reset: bool = True, surrogate_alpha: float = 2.0,
-                       block_m: int = 8, block_n: int = 128):
+                       block_m: int = 8, block_n: int = 128,
+                       interpret: bool | None = None):
     """Differentiable fused LIF: Pallas forward, Pallas surrogate backward.
 
     x: (T, M, N) membrane drive -> binary spikes (T, M, N). Forward output
@@ -385,22 +429,25 @@ def lif_scan_pallas_sg(x, decay: float = 0.5, v_th: float = 1.0,
     (custom_vjp fwd), so inference pays nothing for differentiability.
     """
     return lif_scan_pallas(x, decay=decay, v_th=v_th, soft_reset=soft_reset,
-                           block_m=block_m, block_n=block_n)
+                           block_m=block_m, block_n=block_n,
+                           interpret=interpret)
 
 
-def _sg_fwd(x, decay, v_th, soft_reset, surrogate_alpha, block_m, block_n):
+def _sg_fwd(x, decay, v_th, soft_reset, surrogate_alpha, block_m, block_n,
+            interpret):
     s, vres = _lif_fwd_pallas(x, decay=decay, v_th=v_th,
                               soft_reset=soft_reset, block_m=block_m,
-                              block_n=block_n)
+                              block_n=block_n, interpret=interpret)
     return s, vres
 
 
 def _sg_bwd(decay, v_th, soft_reset, surrogate_alpha, block_m, block_n,
-            vres, g):
+            interpret, vres, g):
     dx = _lif_bwd_pallas(vres, g, decay=decay, v_th=v_th,
                          soft_reset=soft_reset,
                          surrogate_alpha=surrogate_alpha,
-                         block_m=block_m, block_n=block_n)
+                         block_m=block_m, block_n=block_n,
+                         interpret=interpret)
     return (dx,)
 
 

@@ -614,22 +614,19 @@ def _apec_decompose_packed_jit(p2, *, g, block_m, block_n):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("g", "block_m", "block_n", "block_k",
-                                    "pipeline"))
+                   static_argnames=("g", "block_m", "block_n", "block_k"))
 def _apec_matmul_packed_core(res2, ov2, w2, csr, occ_res, occ_ov, *, g,
-                             block_m, block_n, block_k, pipeline=False):
+                             block_m, block_n, block_k):
     return apec_matmul_packed_csr_pallas(res2, ov2, w2, g, csr, occ_res,
                                          occ_ov, block_m=block_m,
-                                         block_n=block_n, block_k=block_k,
-                                         pipeline=pipeline)
+                                         block_n=block_n, block_k=block_k)
 
 
 def apec_matmul_packed(s, w: jax.Array, g: int = 2, *,
                        packed_k: int | None = None,
                        occupancy: jax.Array | None = None,
                        block_m: int = 128, block_n: int = 128,
-                       block_k: int = 128,
-                       pipeline: bool = False) -> jax.Array:
+                       block_k: int = 128) -> jax.Array:
     """Fused APEC matmul staying in the packed domain end to end.
 
     The overlap/residual decomposition is already bitwise on uint32 words
@@ -680,8 +677,7 @@ def apec_matmul_packed(s, w: jax.Array, g: int = 2, *,
         occ_ov_steps = (occ_ov[steps] * csr.valid).astype(jnp.int32)
     out = _apec_matmul_packed_core(res_p, ov_p, w2, csr, occ_res_steps,
                                    occ_ov_steps, g=g, block_m=block_m,
-                                   block_n=block_n, block_k=block_k,
-                                   pipeline=pipeline)
+                                   block_n=block_n, block_k=block_k)
     out = out[:p_orig, :n_orig]
     return out.reshape(lead + (p_pos, w.shape[-1])).astype(w.dtype)
 
@@ -698,8 +694,7 @@ def _conv_pads(size: int, k: int, stride: int, padding: str):
 
 def econv_packed(s, w: jax.Array, *, stride: int = 1,
                  padding: str = "SAME", packed_k: int | None = None,
-                 occupancy: jax.Array | None = None,
-                 pipeline: bool = False) -> jax.Array:
+                 occupancy: jax.Array | None = None) -> jax.Array:
     """Event conv with the payload packed end to end.
 
     im2col runs in the WORD domain: channels are the packed axis, so a
@@ -753,6 +748,5 @@ def econv_packed(s, w: jax.Array, *, stride: int = 1,
     if occupancy is not None and ci % PACK:
         occupancy = None               # dense-patch tiling doesn't align
     out = spike_matmul_packed(patches.reshape(n * ho * wo, kh * kw_ * ciw),
-                              w2, packed_k=k_eff, occupancy=occupancy,
-                              pipeline=pipeline)
+                              w2, packed_k=k_eff, occupancy=occupancy)
     return out.reshape(n, ho, wo, co)

@@ -10,7 +10,9 @@ Two kernels (stage 1 is a reduction, stage 2 elementwise, matching the
 paper's two hardware stages):
 
   status:  grid (BH, N/bn); each program ORs a (bn, dw) K AND V block into
-           a (1, dw) status row. The N-axis is the innermost (sequential)
+           a (1, 1, dw) status row of a (BH, 1, dw) array — the trailing
+           (1, dw) block equals the array's dims, which Mosaic's (8, 128)
+           block rule accepts. The N-axis is the innermost (sequential)
            grid dim, so revisiting the same output block accumulates.
   apply:   grid (BH, N/bn); out = Q AND broadcast(status).
 
@@ -26,14 +28,25 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _or_fold_rows(x):
+    """(r, dw) -> (1, dw) column-OR by halving static slices (Mosaic has
+    no lowering for a bitwise-OR `reduce`)."""
+    while x.shape[0] > 1:
+        r = x.shape[0]
+        h = (r + 1) // 2
+        folded = x[:r - h] | x[h:]
+        x = folded if r == 2 * h else jnp.concatenate(
+            [folded, x[r - h:h]], axis=0)
+    return x
+
+
 def _status_kernel(k_ref, v_ref, status_ref):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         status_ref[...] = jnp.zeros_like(status_ref)
 
     kv = k_ref[0] & v_ref[0]                       # (bn, dw) AND
-    folded = jax.lax.reduce(kv, jnp.uint32(0), jax.lax.bitwise_or, (0,))
-    status_ref[...] |= folded[None, :]
+    status_ref[0] |= _or_fold_rows(kv)
 
 
 def _apply_kernel(q_ref, status_ref, out_ref):
@@ -58,11 +71,11 @@ def sdsa_status_pallas(
             pl.BlockSpec((1, block_n, dw), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_n, dw), lambda b, i: (b, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, dw), lambda b, i: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, dw), jnp.uint32),
+        out_specs=pl.BlockSpec((1, 1, dw), lambda b, i: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, 1, dw), jnp.uint32),
         interpret=interpret,
     )(k_packed, v_packed)
-    return out
+    return out[:, 0, :]
 
 
 def sdsa_apply_pallas(

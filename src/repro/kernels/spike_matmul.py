@@ -47,16 +47,18 @@ from repro.core.spikes import (PACK, TileCSR, occupancy_to_csr,
 
 def _spike_matmul_kernel(occ_ref, s_ref, w_ref, out_ref, acc_ref, *,
                          k_steps: int):
-    @pl.when(pl.program_id(2) == 0)
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(occ_ref[0, 0] > 0)
+    @pl.when(occ_ref[pl.program_id(0) * k_steps + kk] > 0)
     def _accumulate():
         acc_ref[...] += jnp.dot(
             s_ref[...], w_ref[...], preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(2) == k_steps - 1)
+    @pl.when(kk == k_steps - 1)
     def _flush():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
@@ -96,20 +98,26 @@ def spike_matmul_pallas(
 
     k_steps = k // block_k
     kernel = functools.partial(_spike_matmul_kernel, k_steps=k_steps)
-    return pl.pallas_call(
-        kernel,
+    # The map rides in SMEM as a scalar-prefetch operand (flat, row-major
+    # (M/bm, K/bk)): a per-step (1, 1) SMEM block breaks Mosaic's (8, 128)
+    # block rule.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(m // block_m, n // block_n, k_steps),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, kk: (i, kk),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((block_m, block_k), lambda i, j, kk, occ: (i, kk)),
+            pl.BlockSpec((block_k, block_n), lambda i, j, kk, occ: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda i, j, kk, occ: (i, j)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         interpret=interpret,
-    )(occupancy, s, w)
+    )(occupancy.reshape(-1), s, w)
 
 
 # ---------------------------------------------------------------- CSR grid
@@ -246,7 +254,7 @@ def spike_matmul_csr_pallas(
     if pipeline:
         kernel = functools.partial(_spike_matmul_csr_pipe_kernel,
                                    block_k=block_k, block_n=block_n)
-        w_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        w_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [pltpu.VMEM((block_m, block_n), jnp.float32),
                    pltpu.VMEM((2, block_k, block_n), jnp.float32),
                    pltpu.SemaphoreType.DMA((2,))]
@@ -390,7 +398,7 @@ def spike_matmul_packed_csr_pallas(
     if pipeline:
         kernel = functools.partial(_spike_matmul_packed_csr_pipe_kernel,
                                    block_k=block_k, block_n=block_n)
-        w_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        w_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [pltpu.VMEM((block_m, block_n), jnp.float32),
                    pltpu.VMEM((2, block_k, block_n), jnp.float32),
                    pltpu.SemaphoreType.DMA((2,))]
@@ -457,53 +465,6 @@ def _apec_matmul_packed_csr_kernel(row_ref, kidx_ref, occ_res_ref,
         out_ref[...] = (acc_ref[...] + ov_rep).astype(out_ref.dtype)
 
 
-def _apec_matmul_packed_csr_pipe_kernel(row_ref, kidx_ref, occ_res_ref,
-                                        occ_ov_ref, res_ref, ov_ref, w_hbm,
-                                        out_ref, acc_ref, acc_ov_ref, wbuf,
-                                        sem, *, g: int, block_k: int,
-                                        block_n: int):
-    """Pipelined twin of `_apec_matmul_packed_csr_kernel`: one prefetched
-    weight tile serves both dots of a union step, so the DMA gate is the
-    union occupancy (either operand live)."""
-    t = pl.program_id(1)
-    n_t = pl.num_programs(1)
-    row = row_ref[t]
-
-    def gate(u):
-        return (occ_res_ref[u] > 0) | (occ_ov_ref[u] > 0)
-
-    wait_resident = _weight_prefetch(gate, kidx_ref, w_hbm, wbuf, sem,
-                                     block_k=block_k, block_n=block_n)
-
-    @pl.when((t == 0) | (row != row_ref[jnp.maximum(t - 1, 0)]))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        acc_ov_ref[...] = jnp.zeros_like(acc_ov_ref)
-
-    @pl.when(gate(t))
-    def _land():
-        wait_resident()
-
-    @pl.when(occ_res_ref[t] > 0)
-    def _acc_res():
-        acc_ref[...] += jnp.dot(
-            _unpack_tile(res_ref[...], block_k), wbuf[t % 2],
-            preferred_element_type=jnp.float32)
-
-    @pl.when(occ_ov_ref[t] > 0)
-    def _acc_ov():
-        acc_ov_ref[...] += jnp.dot(
-            _unpack_tile(ov_ref[...], block_k), wbuf[t % 2],
-            preferred_element_type=jnp.float32)
-
-    @pl.when((t == n_t - 1) | (row_ref[jnp.minimum(t + 1, n_t - 1)] != row))
-    def _flush():
-        bmg, bn = acc_ov_ref.shape
-        ov_rep = jnp.broadcast_to(acc_ov_ref[...][:, None, :],
-                                  (bmg, g, bn)).reshape(bmg * g, bn)
-        out_ref[...] = (acc_ref[...] + ov_rep).astype(out_ref.dtype)
-
-
 def apec_matmul_packed_csr_pallas(
     res: jax.Array,
     ov: jax.Array,
@@ -517,7 +478,6 @@ def apec_matmul_packed_csr_pallas(
     block_n: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
-    pipeline: bool = False,
 ) -> jax.Array:
     """Fused APEC matmul over the event-compacted grid, packed operands.
 
@@ -541,21 +501,12 @@ def apec_matmul_packed_csr_pallas(
         raise ValueError(
             f"(M,KW,N)=({m},{kw},{n}) must tile by ({block_m},{bkw},{block_n})")
 
-    if pipeline:
-        kernel = functools.partial(_apec_matmul_packed_csr_pipe_kernel, g=g,
-                                   block_k=block_k, block_n=block_n)
-        w_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        scratch = [pltpu.VMEM((block_m, block_n), jnp.float32),
-                   pltpu.VMEM((block_m // g, block_n), jnp.float32),
-                   pltpu.VMEM((2, block_k, block_n), jnp.float32),
-                   pltpu.SemaphoreType.DMA((2,))]
-    else:
-        kernel = functools.partial(_apec_matmul_packed_csr_kernel, g=g,
-                                   block_k=block_k)
-        w_spec = pl.BlockSpec((block_k, block_n),
-                              lambda j, t, row, kidx, o1, o2: (kidx[t], j))
-        scratch = [pltpu.VMEM((block_m, block_n), jnp.float32),
-                   pltpu.VMEM((block_m // g, block_n), jnp.float32)]
+    kernel = functools.partial(_apec_matmul_packed_csr_kernel, g=g,
+                               block_k=block_k)
+    w_spec = pl.BlockSpec((block_k, block_n),
+                          lambda j, t, row, kidx, o1, o2: (kidx[t], j))
+    scratch = [pltpu.VMEM((block_m, block_n), jnp.float32),
+               pltpu.VMEM((block_m // g, block_n), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n // block_n, csr.n_steps),
@@ -697,7 +648,7 @@ def apec_matmul_csr_pallas(
     if pipeline:
         kernel = functools.partial(_apec_matmul_csr_pipe_kernel, g=g,
                                    block_k=block_k, block_n=block_n)
-        w_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        w_spec = pl.BlockSpec(memory_space=pl.ANY)
         scratch = [pltpu.VMEM((block_m, block_n), jnp.float32),
                    pltpu.VMEM((block_m // g, block_n), jnp.float32),
                    pltpu.VMEM((2, block_k, block_n), jnp.float32),

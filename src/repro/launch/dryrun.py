@@ -35,7 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import registry
 from repro.launch import flops as flops_mod
 from repro.launch import hlo_analysis, specs, steps
-from repro.launch.mesh import make_production_mesh, chips, use_concrete_mesh
+from repro.launch.mesh import make_production_mesh, chips
 from repro.models import lm
 from repro.optim import adamw
 from repro.runtime import sharding
@@ -96,7 +96,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     repl = NamedSharding(mesh, P())
 
     t0 = time.time()
-    with mesh, use_concrete_mesh(mesh):
+    with mesh, jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             opt_abs = jax.eval_shape(functools.partial(
                 adamw.init, cfg=adamw.AdamWConfig(

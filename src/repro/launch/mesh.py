@@ -1,4 +1,4 @@
-"""Production mesh construction (deliverable e) + JAX version compat.
+"""Production mesh construction (deliverable e).
 
 A FUNCTION, not a module constant: importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before any jax import; smoke
@@ -7,20 +7,13 @@ tests see the real single CPU device).
 Target: TPU v5e pods — 256 chips/pod as a (16, 16) (data, model) mesh;
 multi-pod prepends a "pod" axis: (2, 16, 16). Hardware constants used by
 the roofline are defined here as the single source of truth.
-
-Compat: this repo runs on JAX back to 0.4.37, which predates
-`jax.sharding.AxisType`, the `axis_types=` kwarg of `jax.make_mesh`, the
-two-argument `AbstractMesh(shape, names)` signature, and
-`jax.sharding.set_mesh`. The helpers below paper over all four; every
-mesh construction in src/ and tests/ goes through them.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 # TPU v5e per-chip constants (roofline denominators).
@@ -29,80 +22,21 @@ HBM_BW = 819e9                  # bytes/s
 ICI_BW_PER_LINK = 50e9          # bytes/s/link
 
 
-# ------------------------------------------------------------ compat shims
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters
-    if hasattr(jax, "make_mesh") else False)
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (Auto,)*n}`` on JAX versions that support it, else
-    ``{}`` (pre-AxisType JAX treats every axis as Auto already)."""
-    if AxisType is None or not _MAKE_MESH_HAS_AXIS_TYPES:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices=None) -> jax.sharding.Mesh:
-    """`jax.make_mesh` with Auto axis types where the installed JAX has
-    them, and without the kwarg where it doesn't."""
-    kwargs = axis_types_kwargs(len(tuple(axes)))
-    if devices is not None:
-        kwargs["devices"] = devices
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
-
-
-def abstract_mesh(shape: Sequence[int],
-                  axes: Sequence[str]) -> "jax.sharding.AbstractMesh":
-    """Device-free mesh for spec validation, on old and new signatures:
-    new JAX takes (axis_sizes, axis_names); 0.4.x takes shape_tuple."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
-@contextlib.contextmanager
-def use_concrete_mesh(mesh: Optional[jax.sharding.Mesh]):
-    """`jax.sharding.set_mesh` where it exists; no-op otherwise (the
-    `with mesh:` context callers already hold covers pjit resolution)."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is None or mesh is None:
-        yield
-    else:
-        with set_mesh(mesh):
-            yield
+    """`jax.make_mesh` with Auto axis types (its default is Explicit,
+    which the pjit-style shardings in this repo do not use)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(tuple(axes)),
+                         devices=devices)
 
 
 def current_mesh():
-    """The mesh installed by ``with mesh:`` / ``set_mesh`` — the abstract
-    mesh on new JAX, the physical context mesh on 0.4.x — or None when no
-    mesh context is active (callers fall back to unsharded paths)."""
-    get_abs = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abs is not None:
-        m = get_abs()
-        if m is not None and getattr(m, "axis_names", ()):
-            return m
-        return None
-    from jax.interpreters import pxla
-    m = pxla.thread_resources.env.physical_mesh
-    return m if getattr(m, "axis_names", ()) else None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = False):
-    """`jax.shard_map` across JAX versions (kwarg renamed check_rep ->
-    check_vma in new releases; old releases only have the experimental
-    entry point)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_rep)
+    """The abstract mesh installed by ``jax.sharding.set_mesh``, or None
+    when no mesh context is active (callers fall back to unsharded
+    paths)."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m is not None and m.axis_names else None
 
 
 # --------------------------------------------------------------- factories

@@ -546,6 +546,8 @@ def main():
                          "unchanged — sharding the slot batch is the "
                          "deployment's jit in_shardings' job")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = (registry.get_reduced(args.arch) if args.reduced
            else registry.get_config(args.arch))
     if args.backend:

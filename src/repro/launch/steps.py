@@ -17,20 +17,21 @@ from repro.models import lm
 from repro.optim import adamw, grad_compress, schedule as sched
 
 
-def _under_mesh(fn: Callable, mesh) -> Callable:
+def _under_mesh(fn: Callable, mesh, split_kernels: bool = False) -> Callable:
     """Wrap a step function so kernel dispatch resolves mesh-aware while
     it traces: every registry op inside sees the ambient mesh (per-shard
     capability checks, mesh_aware filtering). Resolution is trace-time,
     so wrapping the function — not the call site — is what guarantees a
     later retrace (new shapes, donated-buffer miss) still resolves under
-    the mesh."""
+    the mesh. `split_kernels` (the train step, whose params are placed on
+    the mesh): compiled kernels run per data shard (`dispatch.use_mesh`)."""
     if mesh is None:
         return fn
     from repro.kernels import dispatch
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
-        with dispatch.use_mesh(mesh):
+        with dispatch.use_mesh(mesh, split_kernels=split_kernels):
             return fn(*args, **kwargs)
     return wrapped
 
@@ -89,7 +90,7 @@ def make_train_step(
             metrics = {"loss": loss,
                        "grad_norm": adamw.global_norm(grads)}
             return new_params, new_opt, metrics
-        return _under_mesh(train_step, mesh)
+        return _under_mesh(train_step, mesh, split_kernels=True)
 
     def train_step_ef(params, opt_state, ef_state, batch):
         loss, grads = grads_of(params, batch)
@@ -100,7 +101,7 @@ def make_train_step(
             grads, opt_state, params, opt_cfg, lr_scale)
         metrics = {"loss": loss, "grad_norm": adamw.global_norm(grads)}
         return new_params, new_opt, new_ef, metrics
-    return _under_mesh(train_step_ef, mesh)
+    return _under_mesh(train_step_ef, mesh, split_kernels=True)
 
 
 def make_prefill(cfg: LMConfig, spiking: bool, mesh=None) -> Callable:

@@ -25,6 +25,7 @@ from repro.configs import registry
 from repro.configs.base import LMConfig
 from repro.data import pipeline, synthetic
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 from repro.optim import adamw, schedule as sched
@@ -133,6 +134,7 @@ def main():
     ap.add_argument("--dense", action="store_true",
                     help="dense baseline instead of spiking")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = (registry.get_reduced(args.arch) if args.reduced
            else registry.get_config(args.arch))
     out = train_loop(cfg, steps=args.steps, batch=args.batch, seq=args.seq,

@@ -130,7 +130,12 @@ def lif_fire_events(x: jax.Array, lif_cfg: LIFConfig,
     backends. Forward-only: the words are stop-gradient aux, so packed
     mode is an inference path (training keeps dense spikes).
     """
-    from repro.kernels.dispatch import dispatch
+    from repro.kernels.dispatch import dispatch, packed_kernels_available
+    if packed and not packed_kernels_available():
+        raise NotImplementedError(
+            f"packed spike payloads have no {jax.default_backend()} kernels "
+            f"(the packed-csr family is registered for the CPU interpreter "
+            f"only); run with SpikingConfig(packed=False)")
     s, occ, chunks = dispatch("lif_scan_occ", x, decay=lif_cfg.decay,
                               v_th=lif_cfg.v_th,
                               soft_reset=lif_cfg.soft_reset,

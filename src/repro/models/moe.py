@@ -231,15 +231,14 @@ def moe_apply_shard_map(
             out_sorted * w_sorted[:, None])
         return jax.lax.psum(local, "model")          # EP combine: (s_loc, d)
 
-    from repro.launch.mesh import shard_map
     P = jax.sharding.PartitionSpec
-    out = shard_map(
+    out = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(bt_axes or None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=P(bt_axes or None, None),
-        check_rep=False,
+        check_vma=False,
     )(xt, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if "shared" in p:

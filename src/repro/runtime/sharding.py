@@ -365,7 +365,6 @@ def event_op_sharded(mesh: Mesh, op: str, s, w, *, csr_stack=None,
                                    shard_occupancy_to_csr,
                                    stack_shard_csrs)
     from repro.kernels import dispatch, ops
-    from repro.launch.mesh import shard_map
 
     if isinstance(s, EventTensor):
         if occupancy is None:
@@ -528,9 +527,9 @@ def event_op_sharded(mesh: Mesh, op: str, s, w, *, csr_stack=None,
             return ops.spike_matmul_csr(sl, wl, local,
                                         pipeline=pipelined)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(row_spec, w_spec) + csr_specs,
-                       out_specs=row_spec)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(row_spec, w_spec) + csr_specs,
+                           out_specs=row_spec, check_vma=False)
 
         # The raw csr wrapper has no autodiff rule (the registry attaches
         # one per backend); give this pass-through the SAME gradient
@@ -591,9 +590,9 @@ def event_op_sharded(mesh: Mesh, op: str, s, w, *, csr_stack=None,
             return dispatch.call_backend(op, be.name, sl, wl,
                                          occupancy=occl, **kwargs)
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(row_spec, w_spec, occ_spec),
-                       out_specs=row_spec)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(row_spec, w_spec, occ_spec),
+                           out_specs=row_spec, check_vma=False)
         out = fn(s, w, occupancy)
     else:
         registered = be.name in dispatch.backend_names(op)
@@ -606,8 +605,8 @@ def event_op_sharded(mesh: Mesh, op: str, s, w, *, csr_stack=None,
                 return be.fn(sl, wl, **kwargs)
             return dispatch.call_backend(op, be.name, sl, wl, **kwargs)
 
-        fn = shard_map(body, mesh=mesh, in_specs=(row_spec, w_spec),
-                       out_specs=row_spec)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(row_spec, w_spec),
+                           out_specs=row_spec, check_vma=False)
         out = fn(s, w)
     return (out, _report(be.name, attribution, occupancy_source)) \
         if with_report else out

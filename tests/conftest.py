@@ -42,7 +42,7 @@ MULTIDEVICE_SCRIPT = textwrap.dedent("""
 
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import make_mesh, use_concrete_mesh
+    from repro.launch.mesh import make_mesh
 
     def section(name, fn):
         try:
@@ -222,7 +222,7 @@ MULTIDEVICE_SCRIPT = textwrap.dedent("""
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32),
                               jnp.float32)
         ref = moe.moe_apply(p, x, top_k=2, capacity_factor=8.0)
-        with mesh, use_concrete_mesh(mesh):
+        with mesh, jax.sharding.set_mesh(mesh):
             p_sh = jax.device_put(p, {
                 "router": NamedSharding(mesh, P(None, None)),
                 "w_gate": NamedSharding(mesh, P("model", None, None)),
@@ -380,6 +380,45 @@ MULTIDEVICE_SCRIPT = textwrap.dedent("""
                 mesh8, "spike_matmul", ss, w, occupancy=occ)))(s)
             np.testing.assert_allclose(np.asarray(gs), gs_ref, atol=1e-5)
 
+    def gspmd_shard():
+        # A `per_data_shard` backend (the TPU lif_scan/causal_sdsa
+        # kernels, which GSPMD cannot partition) runs per data shard in a
+        # shard_map only where a step asks for it (the sharded train
+        # step); the interpret twin stands in for the compiled kernel.
+        import dataclasses
+        from repro.kernels import dispatch
+        mesh = make_mesh((4, 2), ("data", "model"))
+        kw = dict(decay=0.5, v_th=1.0, soft_reset=True)
+        be = dataclasses.replace(
+            dispatch.get_backend("lif_scan", "pallas-interpret"),
+            per_data_shard=True)
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 16, 128)) * 2
+        x1 = x[:, :1]                           # batch 1, on no mesh
+        assert dispatch._gspmd_shard_wrap(be, (x,)) is be   # no mesh
+        # A serve step under the mesh (unplaced params/state): run whole.
+        with dispatch.use_mesh(mesh):
+            assert dispatch._gspmd_shard_wrap(be, (x,)) is be
+            assert dispatch._gspmd_shard_wrap(be, (x1,)) is be
+        with dispatch.use_mesh(mesh, split_kernels=True):
+            wrapped = dispatch._gspmd_shard_wrap(be, (x,))
+            whole = dispatch._gspmd_shard_wrap(be, (x1,))
+        ref1 = dispatch.call_backend("lif_scan", "ref", x1, **kw)
+        for w_ in (be, whole):                  # batch 1 the mesh can't split
+            out1 = jax.jit(lambda y, _w=w_: _w.fn(y, **kw))(x1)
+            np.testing.assert_array_equal(np.asarray(out1), np.asarray(ref1))
+        xs = jax.device_put(x, NamedSharding(mesh, P(None, "data")))
+        f = jax.jit(lambda y: wrapped.fn(y, **kw))
+        assert "shard_map" in str(jax.make_jaxpr(f)(xs))
+        out = f(xs)
+        ref = dispatch.call_backend("lif_scan", "ref", x, **kw)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        assert len(out.sharding.device_set) == 8
+        g = jax.jit(jax.grad(lambda y: jnp.sum(wrapped.fn(y, **kw))))(xs)
+        g_ref = jax.grad(lambda y: jnp.sum(
+            dispatch.call_backend("lif_scan", "ref", y, **kw)))(x)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref),
+                                   atol=1e-5)
+
     section("CKPT_ELASTIC", ckpt_elastic)
     section("ELASTIC_E2E", elastic_e2e)
     section("ELASTIC_DRILL", elastic_drill)
@@ -388,6 +427,7 @@ MULTIDEVICE_SCRIPT = textwrap.dedent("""
     section("MESH_DISPATCH", mesh_dispatch)
     section("EVENT_TENSOR", event_tensor)
     section("REBALANCE_PIPE", rebalance_pipe)
+    section("GSPMD_SHARD", gspmd_shard)
 """)
 
 

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.core import spikes as spikes_mod
 from repro.core.events import (EventTensor, conv_patch_occupancy,
@@ -203,12 +204,12 @@ def _dense_occ_reductions(jaxpr, min_reduced=4096):
             for v in eqn.params.values():
                 for sub in jax.tree.leaves(
                         v, is_leaf=lambda x: isinstance(
-                            x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
+                            x, (Jaxpr, ClosedJaxpr))):
+                    if isinstance(sub, ClosedJaxpr):
                         walk(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
+                    elif isinstance(sub, Jaxpr):
                         walk(sub)
-    walk(jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr)
+    walk(jaxpr.jaxpr if isinstance(jaxpr, ClosedJaxpr) else jaxpr)
     return found
 
 

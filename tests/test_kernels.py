@@ -42,6 +42,30 @@ def test_lif_wrapper_arbitrary_shape():
     np.testing.assert_allclose(out, expect, atol=0)
 
 
+@pytest.mark.parametrize("packed", [False, True])
+def test_lif_occ_row_split_matches_one_call(monkeypatch, packed):
+    """Rows split across calls when one SMEM count table would overflow:
+    spikes/words and counts must equal the single-call result."""
+    from repro.kernels import lif_scan
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 64, 256)) * 2
+
+    def run():
+        if packed:
+            return lif_scan.lif_scan_occ_packed_pallas(x, interpret=True)
+        return lif_scan._lif_occ_pallas(
+            x, decay=0.5, v_th=1.0, soft_reset=True, block_m=8,
+            block_n=128, emit_vres=True, interpret=True)
+    whole = run()
+    monkeypatch.setattr(lif_scan, "_COUNT_WORDS", 8)   # 16 rows per call
+    split = run()
+    assert len(split) == len(whole)
+    for a, b in zip(split, whole):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    spikes = whole[0] if not packed else unpack_spikes(whole[0], axis=-1)
+    counts = spikes.reshape(2, 8, 8, 2, 128).sum(axis=(2, 4))
+    np.testing.assert_array_equal(np.asarray(whole[1]), np.asarray(counts))
+
+
 # -------------------------------------------------------------------- sdsa
 @pytest.mark.parametrize("bh,n,dw", [(2, 16, 2), (4, 256, 4), (1, 512, 1),
                                      (8, 64, 8)])

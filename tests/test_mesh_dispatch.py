@@ -94,17 +94,17 @@ def test_mesh_auto_resolution_records_degrade_attribution():
 
 
 def test_data_shard_count_reads_batch_axes_only():
-    from repro.launch.mesh import abstract_mesh
+    from jax.sharding import AbstractMesh
     assert dispatch.data_shard_count(None) == 1
     assert dispatch.data_shard_count(8) == 8
-    m = abstract_mesh((2, 2, 2), ("pod", "data", "model"))
+    m = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     assert dispatch.data_shard_count(m) == 4          # pod*data, not model
     assert dispatch.data_shard_count(
-        abstract_mesh((4, 2), ("data", "model"))) == 4
+        AbstractMesh((4, 2), ("data", "model"))) == 4
 
 
 def test_mesh_one_shard_is_plain_resolution():
-    from repro.launch.mesh import abstract_mesh
+    from jax.sharding import AbstractMesh
     s = _spikes(jax.random.PRNGKey(4), (512, 256))
     w = jnp.zeros((256, 64), jnp.float32)
     with dispatch.use_backend(CSR, op="spike_matmul"):
@@ -112,7 +112,7 @@ def test_mesh_one_shard_is_plain_resolution():
         # a model-only mesh shards features, not event rows: no gate
         assert dispatch.resolve_name(
             "spike_matmul", s, w,
-            mesh=abstract_mesh((4,), ("model",))) == CSR
+            mesh=AbstractMesh((4,), ("model",))) == CSR
 
 
 def test_dispatch_entry_accepts_mesh_and_matches_oracle():
@@ -131,12 +131,14 @@ def test_steps_factory_traces_under_mesh():
     seen = []
 
     def probe(x):
-        seen.append(dispatch.ambient_mesh())
+        seen.append((dispatch.ambient_mesh(), dispatch._SPLIT[-1]))
         return x
 
-    wrapped = steps_mod._under_mesh(probe, 8)
-    jax.jit(wrapped)(jnp.zeros((2,)))
-    assert seen == [8]
+    jax.jit(steps_mod._under_mesh(probe, 8))(jnp.zeros((2,)))
+    jax.jit(steps_mod._under_mesh(probe, 8, split_kernels=True))(
+        jnp.zeros((3,)))
+    assert seen == [(8, False), (8, True)]
+    assert dispatch._SPLIT == []                 # context popped
     assert steps_mod._under_mesh(probe, None) is probe
 
 
@@ -328,3 +330,13 @@ def test_mesh_dispatch_multidevice_parity(multidevice_run):
     degrades with attribution. (Payload in conftest.MULTIDEVICE_SCRIPT.)
     """
     multidevice_run.check("MESH_DISPATCH")
+
+
+def test_compiled_kernels_run_per_shard_under_a_gspmd_mesh(multidevice_run):
+    """A `per_data_shard` backend runs in a shard_map over the data axes
+    when the step asks for it under a concrete Mesh (Mosaic kernels
+    cannot be partitioned by GSPMD), matching the oracle forward and
+    through the surrogate gradient; a serve step under the mesh, or a
+    batch the mesh cannot split, runs it whole and still matches.
+    (Payload in conftest.MULTIDEVICE_SCRIPT.)"""
+    multidevice_run.check("GSPMD_SHARD")

@@ -186,6 +186,29 @@ def test_dense_calls_never_auto_select_packed_backends():
                                                  **kwargs)
 
 
+def test_packed_family_is_registered_for_the_interpreter_only():
+    """No TPU backend consumes packed words (their (128, 4) uint32 blocks
+    break Mosaic's (8, 128) block rule), so the CPU interpreter is the
+    only platform where packed emission is allowed."""
+    for op in ("spike_matmul", "apec_matmul", "econv"):
+        packed = [dispatch.get_backend(op, n)
+                  for n in dispatch.backend_names(op)
+                  if "packed" in dispatch.get_backend(op, n).payload]
+        assert packed and all(b.platforms == ("cpu",) for b in packed), op
+    assert dispatch.packed_kernels_available()
+
+
+def test_packed_emission_raises_where_no_packed_kernels(monkeypatch):
+    """On a platform without packed kernels (the TPU), packed emission
+    must refuse instead of densifying every consumer via the shim."""
+    monkeypatch.setattr(dispatch, "packed_kernels_available", lambda: False)
+    drive = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 64)) * 2
+    with pytest.raises(NotImplementedError, match="packed"):
+        lif_fire_events(drive, LIFConfig(), packed=True)
+    assert lif_fire_events(drive, LIFConfig(), packed=False).spikes \
+        is not None
+
+
 # ----------------------------------------------- pack survival: pooling
 def test_max_pool_packed_is_bitwise_or_of_lanes():
     s = _spikes((2, 8, 8, 64), seed=11, p=0.4)

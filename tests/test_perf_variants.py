@@ -3,16 +3,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import registry
-from repro.launch.mesh import abstract_mesh
 from repro.models import lm, moe
 from repro.runtime import sharding
 
 
 def _mesh():
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_tp2d_param_specs_valid():

@@ -2,18 +2,17 @@
 assigned arch (AbstractMesh — no devices needed)."""
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import registry
-from repro.launch.mesh import abstract_mesh
 from repro.models import lm
 from repro.runtime import sharding
 
 
 def _mesh(multi_pod=False):
     if multi_pod:
-        return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", registry.ARCH_IDS)

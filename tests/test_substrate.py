@@ -144,3 +144,29 @@ def test_straggler_monitor_flags_outliers(monkeypatch):
     assert r["flagged"]
     assert r["exclude_vote"]                  # 2 consecutive -> vote
     assert mon.flagged_steps == [5, 6]
+
+
+# ------------------------------------------------------ compile cache
+def test_compile_cache_defers_to_env_dir(monkeypatch):
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    assert calls == []
+
+
+def test_compile_cache_defaults_to_fixed_repo_dir(monkeypatch):
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = Path(__file__).resolve().parents[1]
+    want = str(repo / ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
