@@ -2,8 +2,7 @@
 
 ``PYTHONPATH=src python -m benchmarks.run`` prints
 ``name,us_per_call,derived`` CSV covering Fig. 2 / Fig. 7 / Fig. 8 /
-Table I / Table II / Fig. 9 plus the roofline summary (if dry-run
-artifacts exist under results/dryrun/) and the kernel-backend sweep.
+Table I / Table II / Fig. 9 and the kernel-backend sweep.
 
 Backend sweeps (speedups are measured, not asserted):
 
@@ -42,7 +41,7 @@ import traceback
 def _suites():
     from . import (e2e_event, fig2_econv_vs_tconv, fig7_apec, fig8_breakdown,
                    fig9_cpu, guard_overhead, hybrid_sweep, kernel_backends,
-                   roofline, serve_bench, sparsity_sweep, table1_resources,
+                   serve_bench, sparsity_sweep, table1_resources,
                    table2_throughput)
     return [
         ("fig2", fig2_econv_vs_tconv.run),
@@ -51,7 +50,6 @@ def _suites():
         ("table1", table1_resources.run),
         ("table2", table2_throughput.run),
         ("fig9", fig9_cpu.run),
-        ("roofline", roofline.run),
         ("backends", kernel_backends.run),
         ("sparsity", sparsity_sweep.run),
         # uint32-packed CSR vs f32 CSR single ops + bytes-moved ledger

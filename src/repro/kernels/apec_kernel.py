@@ -60,4 +60,5 @@ def apec_decompose_packed(
             jax.ShapeDtypeStruct((p, dw), jnp.uint32),
         ),
         interpret=interpret,
+        name="apec_decompose",
     )(s_packed)

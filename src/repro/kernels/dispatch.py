@@ -1164,8 +1164,10 @@ def resolve_attribution(op: str, *args, mesh=None, **kwargs) -> str:
 
 def dispatch(op: str, *args, mesh=None, **kwargs):
     """Run `op` on the resolved backend (`mesh` steers resolution only —
-    it is never forwarded to the backend fn)."""
-    return resolve(op, *args, mesh=mesh, **kwargs).fn(*args, **kwargs)
+    it is never forwarded to the backend fn), under a `named_scope` named
+    after the op, so that a profile attributes its device time to it."""
+    with jax.named_scope(op):
+        return resolve(op, *args, mesh=mesh, **kwargs).fn(*args, **kwargs)
 
 
 def call_backend(op: str, name: str, *args, **kwargs):
@@ -1750,18 +1752,25 @@ def _econv_im2col(s, w, stride, padding, matmul, occupancy=None):
     event-compacted ops.spike_matmul_csr). `occupancy` is a map for the
     PATCH matrix — the input map propagated through the im2col window
     (`core.events.conv_patch_occupancy`), never a re-scan of the
-    (kh*kw-times larger) patch tensor."""
+    (kh*kw-times larger) patch tensor.
+
+    The `im2col` scope holds the patch matrix's whole layout, padded to
+    the kernel's (128, 128) tiles here rather than inside `matmul`, so
+    that a profile tells patch layout from the kernel."""
+    from repro.kernels.ops import _pad_to
     kh, kw, ci, co = w.shape
-    patches = jax.lax.conv_general_dilated_patches(
-        s, (kh, kw), (stride, stride), padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    n, ho, wo, _ = patches.shape
+    with jax.named_scope("im2col"):
+        patches = jax.lax.conv_general_dilated_patches(
+            s, (kh, kw), (stride, stride), padding,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        n, ho, wo, _ = patches.shape
+        rows, _ = _pad_to(patches.reshape(n * ho * wo, -1), 0, 128)
+        rows, _ = _pad_to(rows, 1, 128)
     # patch features are ordered (Ci, kh, kw): transpose weights to match
     # (the carried map is order-agnostic: its k-tiles bound whole rows)
     w2 = jnp.transpose(w, (2, 0, 1, 3)).reshape(ci * kh * kw, co)
-    out = matmul(patches.reshape(n * ho * wo, -1), w2.astype(jnp.float32),
-                 occupancy=occupancy)
-    return out.reshape(n, ho, wo, co)
+    out = matmul(rows, w2.astype(jnp.float32), occupancy=occupancy)
+    return out[:n * ho * wo].reshape(n, ho, wo, co)
 
 
 def _econv_pallas(s, w, *, stride=1, padding="SAME", occupancy=None):

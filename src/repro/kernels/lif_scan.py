@@ -80,6 +80,7 @@ def lif_scan_pallas(
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="lif",
     )(x)
 
 
@@ -158,6 +159,7 @@ def _lif_fwd_pallas(x, *, decay, v_th, soft_reset, block_m, block_n,
                    jax.ShapeDtypeStruct(x.shape, jnp.float32)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="grad_lif_fwd",
     )(x)
 
 
@@ -178,6 +180,7 @@ def _lif_bwd_pallas(vres, g, *, decay, v_th, soft_reset, surrogate_alpha,
         out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="grad_lif_bwd",
     )(vres, g)
 
 
@@ -252,8 +255,8 @@ def _lif_occ_fwd_kernel(x_ref, s_ref, cnt_ref, vres_ref, v_ref, *,
     jax.lax.fori_loop(0, t_steps, body, ())
 
 
-def _occ_call(kernel, x, payload, *, block_m, block_n, interpret):
-    """Run a fused fire+count kernel over x (T, M, N).
+def _occ_call(kernel, x, payload, *, block_m, block_n, interpret, name):
+    """Run a fused fire+count kernel, named `name`, over x (T, M, N).
 
     `payload`: ((dtype, width_div), ...), one per tensor output: block
     (T, block_m, block_n // width_div) of a (T, M, N // width_div) array.
@@ -267,7 +270,7 @@ def _occ_call(kernel, x, payload, *, block_m, block_n, interpret):
     if m > rows_per_call:
         parts = [_occ_call(kernel, x[:, a:a + rows_per_call], payload,
                            block_m=block_m, block_n=block_n,
-                           interpret=interpret)
+                           interpret=interpret, name=name)
                  for a in range(0, m, rows_per_call)]
         return tuple(jnp.concatenate(o, axis=1) for o in zip(*parts))
     mb = m // block_m
@@ -288,6 +291,7 @@ def _occ_call(kernel, x, payload, *, block_m, block_n, interpret):
         + shapes[1:],
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(x)
     return (outs[0], outs[1].reshape(t_steps, mb, nb), *outs[2:])
 
@@ -307,7 +311,8 @@ def _lif_occ_pallas(x, *, decay, v_th, soft_reset, block_m, block_n,
         t_steps=t_steps, decay=decay, v_th=v_th, soft_reset=soft_reset)
     payload = ((x.dtype, 1),) + (((jnp.float32, 1),) if emit_vres else ())
     return _occ_call(kernel, x, payload, block_m=block_m, block_n=block_n,
-                     interpret=interpret)
+                     interpret=interpret,
+                     name="grad_lif_occ_fwd" if emit_vres else "lif_occ")
 
 
 def _lif_occ_packed_kernel(x_ref, p_ref, cnt_ref, v_ref, *, t_steps: int,
@@ -370,7 +375,8 @@ def lif_scan_occ_packed_pallas(x, *, decay: float = 0.5, v_th: float = 1.0,
         _lif_occ_packed_kernel, t_steps=t_steps, decay=decay, v_th=v_th,
         soft_reset=soft_reset)
     return _occ_call(kernel, x, ((jnp.uint32, 32),), block_m=block_m,
-                     block_n=block_n, interpret=interpret)
+                     block_n=block_n, interpret=interpret,
+                     name="lif_occ_packed")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))

@@ -74,6 +74,7 @@ def sdsa_status_pallas(
         out_specs=pl.BlockSpec((1, 1, dw), lambda b, i: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, 1, dw), jnp.uint32),
         interpret=interpret,
+        name="sdsa_status",
     )(k_packed, v_packed)
     return out[:, 0, :]
 
@@ -99,6 +100,7 @@ def sdsa_apply_pallas(
         out_specs=pl.BlockSpec((1, block_n, dw), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, n, dw), jnp.uint32),
         interpret=interpret,
+        name="sdsa_apply",
     )(q_packed, status[:, None, :])
 
 
@@ -160,4 +162,5 @@ def sdsa_causal_status_pallas(
         out_shape=jax.ShapeDtypeStruct((bh, n, dw), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((1, dw), jnp.uint32)],
         interpret=interpret,
+        name="causal_sdsa_status",
     )(kv_packed)

@@ -117,6 +117,7 @@ def spike_matmul_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         interpret=interpret,
+        name="_spike_matmul_predicated",
     )(occupancy.reshape(-1), s, w)
 
 
@@ -280,6 +281,7 @@ def spike_matmul_csr_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         interpret=interpret,
+        name="_spike_matmul_csr_core",
     )(csr.tile_m_idx, csr.tile_k_idx, csr.occ, s, w)
 
 
@@ -425,6 +427,7 @@ def spike_matmul_packed_csr_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         interpret=interpret,
+        name="_spike_matmul_packed_csr",
     )(csr.tile_m_idx, csr.tile_k_idx, csr.occ, p, w)
 
 
@@ -526,6 +529,7 @@ def apec_matmul_packed_csr_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         interpret=interpret,
+        name="apec_matmul_packed_csr",
     )(csr.tile_m_idx, csr.tile_k_idx, occ_res, occ_ov, res, ov, w)
 
 
@@ -678,4 +682,5 @@ def apec_matmul_csr_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), w.dtype),
         interpret=interpret,
+        name="apec_matmul_csr",
     )(csr.tile_m_idx, csr.tile_k_idx, occ_res, occ_ov, res, ov, w)

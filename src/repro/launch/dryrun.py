@@ -16,7 +16,7 @@ ShapeDtypeStruct inputs (no allocation), then record:
   * collective bytes       — HLO-parsed, while-trip-count scaled,
   * analytic step cost     — trip-count-aware FLOPs/bytes (launch.flops),
 
-into results/dryrun/<arch>__<shape>__<mesh>.json for the roofline stage.
+into results/dryrun/<arch>__<shape>__<mesh>.json.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-4b --shape train_4k

@@ -12,8 +12,9 @@ scale bottom-up.
 Byte convention: result-shape bytes of the collective (for all-gather this
 is the gathered size — an upper bound on per-chip wire bytes; for
 all-reduce it equals the tensor size, a lower bound on the 2x ring
-traffic). The roofline applies the per-algorithm wire factors on top
-(see benchmarks/roofline.py).
+traffic). A consumer turning bytes into wire time applies the
+per-algorithm wire factors on top (a ring all-reduce moves about twice
+its payload).
 """
 from __future__ import annotations
 

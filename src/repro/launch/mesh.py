@@ -5,8 +5,8 @@ jax device state (the dry-run sets XLA_FLAGS before any jax import; smoke
 tests see the real single CPU device).
 
 Target: TPU v5e pods — 256 chips/pod as a (16, 16) (data, model) mesh;
-multi-pod prepends a "pod" axis: (2, 16, 16). Hardware constants used by
-the roofline are defined here as the single source of truth.
+multi-pod prepends a "pod" axis: (2, 16, 16). The chip's peaks live with
+the chip benchmark, keyed by device kind (`bench/devices.json`).
 """
 from __future__ import annotations
 
@@ -14,12 +14,6 @@ from typing import Sequence
 
 import jax
 from jax.sharding import AxisType
-
-
-# TPU v5e per-chip constants (roofline denominators).
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
-ICI_BW_PER_LINK = 50e9          # bytes/s/link
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,

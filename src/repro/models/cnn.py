@@ -112,29 +112,39 @@ def vgg11_apply(cfg: CNNConfig, p: Params, x: jax.Array,
 
 
 def _vgg11_body(cfg, p, x, collect_stats):
+    # Each layer runs under a `named_scope` (`encode`, `conv.{i}`,
+    # `pool.{i}`, `head`), so that a profile of the compiled program
+    # attributes device time to layers.
     lif = LIFConfig(decay=cfg.spiking.lif_decay, v_th=cfg.spiking.lif_vth)
     t = cfg.spiking.t_steps
-    q, scale = quantize(x, cfg.direct_coding_bits)
-    s = jnp.broadcast_to((q.astype(jnp.float32) * scale)[None],
-                         (t,) + x.shape)   # direct-coded drive, each step
+    with jax.named_scope("encode"):
+        q, scale = quantize(x, cfg.direct_coding_bits)
+        s = jnp.broadcast_to((q.astype(jnp.float32) * scale)[None],
+                             (t,) + x.shape)   # direct-coded drive, each step
     packed = getattr(cfg.spiking, "packed", False)
     stats: List[jax.Array] = []
+    n_seen = {"conv": 0, "maxpool": 0}
     for layer, w in zip(VGG11_LAYERS, p["convs"]):
+        i = n_seen[layer.kind]
+        n_seen[layer.kind] += 1
         if layer.kind == "maxpool":
             # pooling keeps the carried map alive (tile-map dilation);
             # a packed payload pools its words bitwise-OR.
-            s = max_pool_events(s, layer.pool)
+            with jax.named_scope(f"pool.{i}"):
+                s = max_pool_events(s, layer.pool)
             continue
-        drive = _conv_seq(s, w)
-        s = _fire(drive, lif, packed)     # binary spikes + occupancy map
+        with jax.named_scope(f"conv.{i}"):
+            drive = _conv_seq(s, w)
+            s = _fire(drive, lif, packed)  # binary spikes + occupancy map
         if collect_stats:
             stats.append(s.dense())
     # EAFC head (OPT3): event-driven avgpool+FC over every timestep.
     # `.dense()` is the one explicit unpack point for a packed payload
     # (eafc has no packed backend).
-    logits = jnp.mean(jax.vmap(lambda st: eafc(st, p["fc"],
-                                               cfg.fc_pool))(s.dense()),
-                      axis=0)
+    with jax.named_scope("head"):
+        logits = jnp.mean(jax.vmap(lambda st: eafc(st, p["fc"],
+                                                   cfg.fc_pool))(s.dense()),
+                          axis=0)
     return (logits, stats) if collect_stats else logits
 
 

@@ -55,10 +55,14 @@ def spikingformer_apply(p: Params, x: jax.Array, n_heads: int = 8,
 
 
 def _spikingformer_body(p, x, n_heads, spiking_cfg, collect_stats):
+    # Each layer runs under a `named_scope` (`encode`, `sps.{i}`,
+    # `block.{j}.attn`, `block.{j}.ffn`, `head`), so that a profile of the
+    # compiled program attributes device time to layers.
     lif = LIFConfig(decay=spiking_cfg.lif_decay, v_th=spiking_cfg.lif_vth)
     t = spiking_cfg.t_steps
     b = x.shape[0]
-    s = jnp.broadcast_to(x[None], (t,) + x.shape)
+    with jax.named_scope("encode"):
+        s = jnp.broadcast_to(x[None], (t,) + x.shape)
     stats: List[jax.Array] = []
 
     # SPS: conv -> LIF x4, maxpool after stages 2 and 3 (32 -> 8).
@@ -73,50 +77,64 @@ def _spikingformer_body(p, x, n_heads, spiking_cfg, collect_stats):
     from repro.core.econv import econv, tconv
     packed = getattr(spiking_cfg, "packed", False)
     for i, w in enumerate(p["sps"]):
-        tb = s.shape[:2]
-        flat = s.reshape((-1,) + s.shape[2:])
-        drive = tconv(flat, w) if i == 0 else econv(flat, w)
-        drive = drive.reshape(tb + drive.shape[1:])
-        s = lif_fire_events(drive, lif, packed=packed)
-        if i in (1, 2):
-            s = max_pool_events(s, 2)    # packed payload pools bitwise-OR
-        if collect_stats:
-            stats.append(s.dense())
+        with jax.named_scope(f"sps.{i}"):
+            with jax.named_scope("conv"):
+                tb = s.shape[:2]
+                flat = s.reshape((-1,) + s.shape[2:])
+                drive = tconv(flat, w) if i == 0 else econv(flat, w)
+                drive = drive.reshape(tb + drive.shape[1:])
+            with jax.named_scope("fire"):
+                s = lif_fire_events(drive, lif, packed=packed)
+            if i in (1, 2):
+                with jax.named_scope("pool"):
+                    s = max_pool_events(s, 2)  # packed pools bitwise-OR
+            if collect_stats:
+                stats.append(s.dense())
 
-    dim = s.shape[-1]
-    n_tok = s.shape[2] * s.shape[3]
-    tokens = s.reshape(t, b, n_tok, dim)         # (T,B,N,D), map survives
-    # The membrane residual stream is continuous-valued from here on —
-    # `.dense()` is the explicit unpack at the SPS/transformer boundary.
-    x_mp = tokens.dense()
+    with jax.named_scope(f"sps.{i}"):    # the last stage makes the tokens
+        dim = s.shape[-1]
+        n_tok = s.shape[2] * s.shape[3]
+        tokens = s.reshape(t, b, n_tok, dim)     # (T,B,N,D), map survives
+        # The membrane residual stream is continuous-valued from here on —
+        # `.dense()` is the explicit unpack at the SPS/transformer boundary.
+        x_mp = tokens.dense()
 
-    for blk in p["blocks"]:
-        # SSA: q/k/v spikes -> Attention Core (non-causal OR form). The
-        # head split changes the trailing axis, so no map is carried into
-        # SDSA (which consumes packed words, not occupancy, anyway).
-        sq = lif_fire(x_mp @ blk["w_q"], lif).reshape(
-            t, b, n_tok, n_heads, dim // n_heads)
-        sk = lif_fire(x_mp @ blk["w_k"], lif).reshape(
-            t, b, n_tok, n_heads, dim // n_heads)
-        sv = lif_fire(x_mp @ blk["w_v"], lif).reshape(
-            t, b, n_tok, n_heads, dim // n_heads)
-        attn = dispatch.sdsa(sq.swapaxes(2, 3), sk.swapaxes(2, 3),
-                             sv.swapaxes(2, 3), mode=spiking_cfg.sdsa_mode)
-        attn = attn.swapaxes(2, 3).reshape(t, b, n_tok, dim)
-        if collect_stats:
-            stats.append(attn)
-        x_mp = x_mp + attn @ blk["w_o"]
-        # Spiking MLP (FFN): full-event — both fires carry their maps and
-        # both projections consume them through the registry matmul. In
-        # packed mode both fires emit uint32 words and the projections
-        # route to the packed-csr family (no f32 spikes in between).
-        h = lif_fire_events(x_mp, lif, packed=packed)
-        h = lif_fire_events(dispatch.spike_matmul(h, blk["w_fc1"]), lif,
-                            packed=packed)
-        if collect_stats:
-            stats.append(h.dense())
-        x_mp = x_mp + dispatch.spike_matmul(h, blk["w_fc2"])
+    for j, blk in enumerate(p["blocks"]):
+        with jax.named_scope(f"block.{j}.attn"):
+            # SSA: q/k/v spikes -> Attention Core (non-causal OR form).
+            # The head split changes the trailing axis, so no map is
+            # carried into SDSA (which consumes packed words, not
+            # occupancy, anyway).
+            with jax.named_scope("qkv"):
+                sq = lif_fire(x_mp @ blk["w_q"], lif).reshape(
+                    t, b, n_tok, n_heads, dim // n_heads)
+                sk = lif_fire(x_mp @ blk["w_k"], lif).reshape(
+                    t, b, n_tok, n_heads, dim // n_heads)
+                sv = lif_fire(x_mp @ blk["w_v"], lif).reshape(
+                    t, b, n_tok, n_heads, dim // n_heads)
+            with jax.named_scope("sdsa"):
+                attn = dispatch.sdsa(sq.swapaxes(2, 3), sk.swapaxes(2, 3),
+                                     sv.swapaxes(2, 3),
+                                     mode=spiking_cfg.sdsa_mode)
+                attn = attn.swapaxes(2, 3).reshape(t, b, n_tok, dim)
+            if collect_stats:
+                stats.append(attn)
+            with jax.named_scope("proj"):
+                x_mp = x_mp + attn @ blk["w_o"]
+        with jax.named_scope(f"block.{j}.ffn"):
+            # Spiking MLP (FFN): full-event — both fires carry their maps
+            # and both projections consume them through the registry
+            # matmul. In packed mode both fires emit uint32 words and the
+            # projections route to the packed-csr family (no f32 spikes
+            # in between).
+            h = lif_fire_events(x_mp, lif, packed=packed)
+            h = lif_fire_events(dispatch.spike_matmul(h, blk["w_fc1"]), lif,
+                                packed=packed)
+            if collect_stats:
+                stats.append(h.dense())
+            x_mp = x_mp + dispatch.spike_matmul(h, blk["w_fc2"])
 
-    feats = jnp.mean(lif_fire(x_mp, lif), axis=(0, 2))      # rate + token avg
-    logits = feats @ p["head"]
+    with jax.named_scope("head"):
+        feats = jnp.mean(lif_fire(x_mp, lif), axis=(0, 2))  # rate + tokens
+        logits = feats @ p["head"]
     return (logits, stats) if collect_stats else logits

@@ -11,8 +11,18 @@ lowered as interpreted HLO cannot pass.
 The packed-csr family is absent on purpose: its (block_m, block_k/32)
 word blocks break the (8, 128) rule, so it is registered for the CPU
 interpreter only (see `kernels.dispatch`).
+
+The chip benchmark's timed programs (`bench/configs/<config>.py`) compile
+here too, at batch 1, to hold the names a profile reads them by: every
+kernel's `pallas_call(name=...)`, the per-layer metrics' patterns, and a
+layer `named_scope` over every instruction.
 """
+import collections
+import functools
+import json
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +30,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.spikes import occupancy_to_csr, tile_occupancy
-from repro.kernels import apec_kernel, lif_scan, sdsa_kernel, spike_matmul
+from repro.kernels import (apec_kernel, dispatch, lif_scan, sdsa_kernel,
+                           spike_matmul)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+from bench import BENCH, load_module  # noqa: E402
+from bench import rawtrace  # noqa: E402
 
 M = K = N = 1024          # matmul widths
 T = 4                     # timesteps (the paper's CNNs and SpikingFormer)
@@ -122,3 +139,116 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for shape, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------- the benchmark's programs
+# Every pallas_call's name; the per-layer metrics find kernels by them.
+KERNEL_NAMES = {
+    "lif", "lif_occ", "lif_occ_packed", "grad_lif_fwd", "grad_lif_bwd",
+    "grad_lif_occ_fwd", "_spike_matmul_predicated", "_spike_matmul_csr_core",
+    "_spike_matmul_packed_csr", "apec_matmul_csr", "apec_matmul_packed_csr",
+    "apec_decompose", "sdsa_status", "sdsa_apply", "causal_sdsa_status"}
+# The registry ops whose Pallas routes run each reader's kernels.
+FAMILY_OPS = {"roofline.event_matmul": ("econv", "spike_matmul"),
+              "roofline.lif": ("lif_scan", "lif_scan_occ")}
+LAYER_SCOPES = {
+    "spikingformer-4-256": r"encode|sps\.\d+|block\.\d+\.(attn|ffn)|head",
+    "vgg11": r"encode|conv\.\d+|pool\.\d+|head"}
+CUSTOM_CALL = re.compile(
+    r'^%([\w.\-]+) = .*custom_call_target="tpu_custom_call"')
+HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) ")
+HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = ")
+# an op_name that is a path: the traced program's, not an argument's
+HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*/[^"]*)"')
+
+
+def _instructions(hlo_text):
+    """Each instruction's text, without indent or ROOT, leaving out
+    reducer bodies (`to_apply`), which run as part of their caller."""
+    reducers = set(re.findall(r"to_apply=%([\w.\-]+)", hlo_text))
+    out, computation = [], None
+    for line in hlo_text.splitlines():
+        head = HLO_COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+        elif HLO_INSTRUCTION.match(line) \
+                and computation not in reducers:
+            out.append(re.sub(r"^\s+(ROOT )?", "", line))
+    return out
+
+
+@pytest.fixture(scope="module")
+def bench_programs(one_chip):
+    """{config: (compiled HLO text, resolutions)} of the benchmark's timed
+    programs at batch 1, lowered as the harness lowers them, with dispatch
+    steered to the TPU routes. The jit caches are cleared on both sides,
+    so that no CPU trace is reused here and no TPU trace leaks out."""
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        jax.clear_caches()
+        try:
+            for config in LAYER_SCOPES:
+                base = os.path.join(BENCH, "configs", config)
+                model = load_module(base + ".py")
+                with open(base + ".json") as f:
+                    cfg = json.load(f)
+                params = jax.eval_shape(functools.partial(model.init, cfg),
+                                        jax.random.key(0))
+                params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=one_chip), params)
+                x = jax.ShapeDtypeStruct((1, cfg["img"], cfg["img"],
+                                          cfg["in_ch"]), jnp.float32,
+                                         sharding=one_chip)
+                with jax.default_matmul_precision(cfg["matmul_precision"]), \
+                        dispatch.watch_resolutions() as rec:
+                    lowered = jax.jit(model.program(cfg)).lower(params, x)
+                out[config] = (lowered.compile().as_text(), list(rec))
+        finally:
+            jax.clear_caches()
+    return out
+
+
+@pytest.mark.parametrize("config", sorted(LAYER_SCOPES))
+def test_bench_program_kernels_are_named(config, bench_programs):
+    hlo, _ = bench_programs[config]
+    kernels = [m.group(1) for text in _instructions(hlo)
+               for m in [CUSTOM_CALL.match(text)] if m]
+    assert kernels
+    assert {re.sub(r"\.\d+$", "", k) for k in kernels} <= KERNEL_NAMES
+
+
+@pytest.mark.parametrize("config", sorted(LAYER_SCOPES))
+@pytest.mark.parametrize("metric", sorted(FAMILY_OPS))
+def test_bench_reader_patterns_count_pallas_routes(config, metric,
+                                                   bench_programs):
+    hlo, resolutions = bench_programs[config]
+    patterns = load_module(os.path.join(BENCH, "metrics",
+                                        metric + ".py")).PATTERNS
+    rx = re.compile("|".join(f"(?:{p})" for p in patterns))
+    matched = sum(1 for text in _instructions(hlo) if rx.search(text))
+    routes = sum(1 for r in resolutions if r["op"] in FAMILY_OPS[metric]
+                 and r["backend"].startswith("pallas"))
+    assert routes > 0
+    assert matched == routes
+
+
+@pytest.mark.parametrize("config", sorted(LAYER_SCOPES))
+def test_bench_program_layers_are_scoped(config, bench_programs):
+    hlo, _ = bench_programs[config]
+    scope = re.compile(rf"^({LAYER_SCOPES[config]})$")
+    layers = collections.Counter()
+    for text in _instructions(hlo):
+        op = HLO_OP_NAME.search(text)
+        if op:
+            layers[rawtrace.layer_of(op.group(1))] += 1
+    assert layers
+    assert all(scope.match(layer) for layer in layers), layers
+
+
+@pytest.mark.parametrize("config", sorted(LAYER_SCOPES))
+def test_bench_program_has_im2col(config, bench_programs):
+    hlo, _ = bench_programs[config]
+    assert any(re.search(r"(^|/)im2col/", op.group(1))
+               for text in _instructions(hlo)
+               for op in [HLO_OP_NAME.search(text)] if op)
