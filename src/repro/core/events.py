@@ -399,8 +399,11 @@ def window_occupancy(et: EventTensor, window: Tuple[int, int], stride: int,
     to the 128-row tile map when only that is carried): one cumsum over
     the tiny map, two gathers. Counts over-approximate, but a zero is
     exact: if no input chunk in reach holds events, every output row in
-    the block is all-zero. Returns (tile map (MT_out, KT_out), chunk map
-    (MT_out*16, KT_out)) or (None, None).
+    the block is all-zero. A row block's bound is the same in each of its
+    KT_out k-tiles: it bounds whole output rows, so the order of the
+    features within a row (im2col's (kh, kw, C) in `ops.im2col_rows`)
+    does not change which tiles it marks empty. Returns (tile map
+    (MT_out, KT_out), chunk map (MT_out*16, KT_out)) or (None, None).
     """
     occ = et.occupancy_for(*et.tiling)
     if occ is None or et.ndim < 4:
@@ -480,8 +483,9 @@ def conv_patch_occupancy(et: EventTensor, w_shape: Tuple[int, ...],
                          stride: int, padding: str) -> Optional[jax.Array]:
     """Carried map for the im2col patch matrix of a conv over `et`
     ((N,H,W,C) spikes, HWIO weights): rows = output positions, K =
-    C*kh*kw. None when no map is carried or the geometry is unsupported
-    (the consumer then re-derives)."""
+    kh*kw*C in any feature order (see `window_occupancy`). None when no
+    map is carried or the geometry is unsupported (the consumer then
+    re-derives)."""
     if et.occupancy is None or et.ndim < 4:
         return None
     kh, kw, ci, co = w_shape

@@ -1754,22 +1754,19 @@ def _econv_im2col(s, w, stride, padding, matmul, occupancy=None):
     (`core.events.conv_patch_occupancy`), never a re-scan of the
     (kh*kw-times larger) patch tensor.
 
-    The `im2col` scope holds the patch matrix's whole layout, padded to
-    the kernel's (128, 128) tiles here rather than inside `matmul`, so
-    that a profile tells patch layout from the kernel."""
-    from repro.kernels.ops import _pad_to
+    The patches are copies (`ops.im2col_rows`, the `im2col` kernel), in
+    (kh, kw, Ci) order, so the HWIO weights match them by a reshape. The
+    `im2col` scope holds the patch matrix's whole layout, padded to the
+    kernel's (128, 128) tiles here rather than inside `matmul`, so that a
+    profile tells patch layout from the kernel."""
+    from repro.kernels.ops import _pad_to, im2col_rows
     kh, kw, ci, co = w.shape
+    n = s.shape[0]
     with jax.named_scope("im2col"):
-        patches = jax.lax.conv_general_dilated_patches(
-            s, (kh, kw), (stride, stride), padding,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        n, ho, wo, _ = patches.shape
-        rows, _ = _pad_to(patches.reshape(n * ho * wo, -1), 0, 128)
-        rows, _ = _pad_to(rows, 1, 128)
-    # patch features are ordered (Ci, kh, kw): transpose weights to match
-    # (the carried map is order-agnostic: its k-tiles bound whole rows)
-    w2 = jnp.transpose(w, (2, 0, 1, 3)).reshape(ci * kh * kw, co)
-    out = matmul(rows, w2.astype(jnp.float32), occupancy=occupancy)
+        rows, (ho, wo) = im2col_rows(s, kh, kw, stride, padding, lanes=128)
+        rows, _ = _pad_to(rows, 0, 128)
+    out = matmul(rows, w.reshape(kh * kw * ci, co).astype(jnp.float32),
+                 occupancy=occupancy)
     return out[:n * ho * wo].reshape(n, ho, wo, co)
 
 
@@ -1893,14 +1890,10 @@ def _tconv_pallas(s, w, *, stride=2, padding="SAME"):
     from repro.kernels import ops
     kh, kw, ci, co = w.shape
     up = upsample_events(s, stride, kh, kw, padding)
-    patches = jax.lax.conv_general_dilated_patches(
-        up, (kh, kw), (1, 1), "VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    n, ho, wo, _ = patches.shape
-    w2 = jnp.transpose(w, (2, 0, 1, 3)).reshape(ci * kh * kw, co)
-    out = ops.spike_matmul(patches.reshape(n * ho * wo, -1),
-                           w2.astype(jnp.float32))
-    return out.reshape(n, ho, wo, co)
+    rows, (ho, wo) = ops.im2col_rows(up, kh, kw, 1, "VALID")
+    out = ops.spike_matmul(rows, w.reshape(kh * kw * ci, co).astype(
+        jnp.float32))
+    return out.reshape(up.shape[0], ho, wo, co)
 
 
 register("tconv", "pallas-interpret", platforms=("cpu",), priority=1,

@@ -16,6 +16,7 @@ from repro.core.events import EventTensor
 from repro.core.spikes import (PACK, TileCSR, build_csr, pack_spikes,
                                pack_spikes_padded, packed_tile_occupancy,
                                packed_width, tile_occupancy, unpack_spikes)
+from .im2col import im2col_pallas
 from .lif_scan import (lif_scan_occ_packed_pallas, lif_scan_occ_pallas_sg,
                        lif_scan_pallas_sg)
 from .sdsa_kernel import (sdsa_causal_status_pallas, sdsa_packed,
@@ -682,14 +683,36 @@ def apec_matmul_packed(s, w: jax.Array, g: int = 2, *,
     return out.reshape(lead + (p_pos, w.shape[-1])).astype(w.dtype)
 
 
-def _conv_pads(size: int, k: int, stride: int, padding: str):
-    """(out_size, pad_lo, pad_hi) matching lax's SAME/VALID conventions."""
+def _conv_pads(size: int, k: int, stride: int, padding):
+    """(out_size, pad_lo, pad_hi) matching lax's conventions: "SAME",
+    "VALID", or an explicit (lo, hi) pair for this dimension."""
     if padding == "SAME":
         out = -(-size // stride)
         total = max((out - 1) * stride + k - size, 0)
         return out, total // 2, total - total // 2
-    out = (size - k) // stride + 1
-    return out, 0, 0
+    lo, hi = (0, 0) if padding == "VALID" else padding
+    return (size + lo + hi - k) // stride + 1, lo, hi
+
+
+def im2col_rows(x: jax.Array, kh: int, kw: int, stride: int, padding,
+                lanes: int = 1):
+    """Patch rows of an NHWC map, by copies only: `x` zero-padded as lax
+    pads a conv ("SAME", "VALID" or ((lo, hi), (lo, hi))), then kh*kw
+    static strided windows concatenated on the last axis (the `im2col`
+    kernel), so the features run in (kh, kw, C) order and HWIO weights
+    match them by a plain reshape. `lanes` > 1 rounds K up to its
+    multiple with zeros, in the same concatenation.
+
+    Returns ((N*Ho*Wo, K) rows, (Ho, Wo))."""
+    _, h, w, c = x.shape
+    pad_h, pad_w = (padding, padding) if isinstance(padding, str) \
+        else padding
+    ho, pt, pb = _conv_pads(h, kh, stride, pad_h)
+    wo, pl_, pr = _conv_pads(w, kw, stride, pad_w)
+    xp = jnp.pad(x, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
+    k = kh * kw * c
+    rows = im2col_pallas(xp, kh, kw, stride, (ho, wo), k + (-k % lanes))
+    return rows, (ho, wo)
 
 
 def econv_packed(s, w: jax.Array, *, stride: int = 1,
@@ -731,22 +754,14 @@ def econv_packed(s, w: jax.Array, *, stride: int = 1,
     if ci_w != ci:
         raise ValueError(f"weights expect {ci_w} input channels, packed "
                          f"operand covers {ci}")
-    n, h, wdt, ciw = p.shape
-    ho, pt, pb = _conv_pads(h, kh, stride, padding)
-    wo, pl_, pr = _conv_pads(wdt, kw_, stride, padding)
-    pp = jnp.pad(p, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
-    slices = [
-        pp[:, dy:dy + (ho - 1) * stride + 1:stride,
-           dx:dx + (wo - 1) * stride + 1:stride, :]
-        for dy in range(kh) for dx in range(kw_)
-    ]
-    patches = jnp.concatenate(slices, axis=-1)      # (n, ho, wo, kh*kw*ciw)
+    n, ciw = p.shape[0], p.shape[-1]
+    rows, (ho, wo) = im2col_rows(p, kh, kw_, stride, padding)
     k_eff = kh * kw_ * ciw * PACK
     ci_pad = ciw * PACK
     w2 = jnp.pad(w, ((0, 0), (0, 0), (0, ci_pad - ci), (0, 0)))
     w2 = w2.reshape(kh * kw_ * ci_pad, co)
     if occupancy is not None and ci % PACK:
         occupancy = None               # dense-patch tiling doesn't align
-    out = spike_matmul_packed(patches.reshape(n * ho * wo, kh * kw_ * ciw),
-                              w2, packed_k=k_eff, occupancy=occupancy)
+    out = spike_matmul_packed(rows, w2, packed_k=k_eff,
+                              occupancy=occupancy)
     return out.reshape(n, ho, wo, co)

@@ -236,6 +236,102 @@ def test_spike_ops_preserve_narrow_input_dtypes(op, dtype):
                                atol=ATOL)
 
 
+# ---------------------------------------- im2col by static shifted slices
+# Every channel count the benchmark's convolutions take, strides 1 and 2,
+# SAME/VALID/explicit padding at 3x3, plus a 1x1 and an even 2x2 kernel.
+IM2COL_CASES = (
+    [(ci, 3, stride, pad) for ci in (3, 32, 64, 128, 256)
+     for stride in (1, 2) for pad in ("SAME", "VALID", ((1, 0), (0, 2)))]
+    + [(32, 1, 1, "SAME"), (64, 2, 2, "SAME"), (64, 2, 1, "VALID")])
+
+
+def _assert_im2col_rows_exact(x, k, stride, padding):
+    """`ops.im2col_rows` is lax's patch extraction with the features in
+    (kh, kw, C) order, bit for bit, and zeros up to the 128-lane tile.
+    Returns (Ho, Wo)."""
+    from repro.kernels import ops
+    ci = x.shape[-1]
+    with jax.default_matmul_precision("highest"):
+        lax_patches = jax.lax.conv_general_dilated_patches(
+            x, (k, k), (stride, stride), padding,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    n, ho, wo, _ = lax_patches.shape
+    expect = np.asarray(lax_patches).reshape(n, ho, wo, ci, k, k)
+    expect = expect.transpose(0, 1, 2, 4, 5, 3).reshape(n * ho * wo, -1)
+    rows, hw = ops.im2col_rows(x, k, k, stride, padding)
+    assert hw == (ho, wo)
+    np.testing.assert_array_equal(np.asarray(rows), expect)
+    rows, _ = ops.im2col_rows(x, k, k, stride, padding, lanes=128)
+    assert rows.shape[1] % 128 == 0
+    np.testing.assert_array_equal(np.asarray(rows[:, :expect.shape[1]]),
+                                  expect)
+    assert not np.any(np.asarray(rows[:, expect.shape[1]:]))
+    return ho, wo
+
+
+@pytest.mark.parametrize(
+    "ci,k,stride,padding", IM2COL_CASES,
+    ids=[f"ci{c}-k{k}-s{s}-{p if isinstance(p, str) else 'explicit'}"
+         for c, k, s, p in IM2COL_CASES])
+def test_im2col_rows_exact_and_econv_matches_ref(ci, k, stride, padding):
+    """Patch rows equal lax's (values that are no spikes too: VGG's first
+    conv takes the direct-coded image); econv's Pallas routes built on
+    them match the TConv oracle with and without a carried map."""
+    from repro.core.events import EventTensor, conv_patch_occupancy
+    kx, ks, kw_ = jax.random.split(jax.random.PRNGKey(ci * 10 + k), 3)
+    _assert_im2col_rows_exact(jax.random.normal(kx, (2, 12, 12, ci)), k,
+                              stride, padding)
+
+    # image 0 silent, so the carried map has empty row tiles to skip
+    s = (jax.random.uniform(ks, (2, 12, 12, ci)) < 0.3).astype(jnp.float32)
+    s = s.at[0].set(0.0)
+    w = jax.random.normal(kw_, (k, k, ci, 8)) * (k * k * ci) ** -0.5
+    expect = dispatch.call_backend("econv", dispatch.REF, s, w,
+                                   stride=stride, padding=padding)
+    occ = conv_patch_occupancy(EventTensor.from_spikes(s), w.shape, stride,
+                               padding) if isinstance(padding, str) else None
+    for backend in ("pallas-interpret", "pallas-csr-interpret"):
+        for occupancy in ((None, occ) if occ is not None else (None,)):
+            got = dispatch.call_backend("econv", backend, s, w,
+                                        stride=stride, padding=padding,
+                                        occupancy=occupancy)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(expect),
+                                       atol=ATOL)
+
+
+@pytest.mark.parametrize("n,hw,ci,k,stride,padding", [
+    (2, 64, 3, 3, 1, "SAME"), (1, 96, 64, 3, 2, "SAME"),
+    (1, 160, 16, 2, 2, ((1, 0), (0, 2)))])
+def test_im2col_rows_exact_in_bands_of_large_images(n, hw, ci, k, stride,
+                                                    padding):
+    """An image too large for one kernel step is written in bands of
+    output rows, each reading its overlapping input rows: the rows are
+    still exact."""
+    from repro.kernels import im2col
+    x = jax.random.normal(jax.random.PRNGKey(hw), (n, hw, hw, ci))
+    ho, wo = _assert_im2col_rows_exact(x, k, stride, padding)
+    kk = k * k * ci
+    assert im2col._step_shape(n, ho, wo, ci, kk + (-kk % 128), k * k,
+                              4)[1] < ho
+
+
+@pytest.mark.parametrize("padding", ["SAME", "VALID"])
+def test_tconv_pallas_patch_rows_match_ref(padding):
+    """The transposed conv's Pallas route builds its patches with the same
+    helper and the HWIO weights unpermuted: equal to the oracle at
+    stride 2."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    s = (jax.random.uniform(k1, (2, 7, 7, 32)) < 0.3).astype(jnp.float32)
+    w = jax.random.normal(k2, (3, 3, 32, 16)) * (9 * 32) ** -0.5
+    expect = dispatch.call_backend("tconv", dispatch.REF, s, w, stride=2,
+                                   padding=padding)
+    got = dispatch.call_backend("tconv", "pallas-interpret", s, w, stride=2,
+                                padding=padding)
+    assert got.shape == expect.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(expect),
+                               atol=ATOL)
+
+
 def test_sdsa_ops_handle_non_tile_multiple_token_counts():
     """Token counts whose sublane padding is not a block_n multiple
     (e.g. 384 > 256) must still run on the packed kernels — the wrappers

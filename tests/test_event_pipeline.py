@@ -143,11 +143,8 @@ def test_window_occupancy_edge_parity_nondivisible(h, w, k, stride, padding):
     occ_tr = jax.jit(lambda e: conv_patch_occupancy(
         e, (k, k, c, c), stride, padding))(et)
     np.testing.assert_array_equal(np.asarray(occ_np), np.asarray(occ_tr))
-    patches = jax.lax.conv_general_dilated_patches(
-        sp, (k, k), (stride, stride), padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    true_occ = np.asarray(ops.padded_occupancy(
-        patches.reshape(-1, patches.shape[-1])))
+    rows, _ = ops.im2col_rows(sp, k, k, stride, padding)
+    true_occ = np.asarray(ops.padded_occupancy(rows))
     # conservative: never marks a truly occupied tile empty
     assert bool(np.all((true_occ == 0) | (np.asarray(occ_np) > 0)))
 
@@ -171,11 +168,8 @@ def test_window_occupancy_empty_image_stays_empty_under_stride():
     et = EventTensor.from_spikes(sp)
     w = jax.random.normal(jax.random.PRNGKey(9), (3, 3, 32, 8))
     occ_p = conv_patch_occupancy(et, w.shape, 1, "SAME")
-    patches = jax.lax.conv_general_dilated_patches(
-        sp, (3, 3), (1, 1), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    true_occ = np.asarray(ops.padded_occupancy(
-        patches.reshape(2 * 16 * 16, -1)))
+    rows, _ = ops.im2col_rows(sp, 3, 3, 1, "SAME")
+    true_occ = np.asarray(ops.padded_occupancy(rows))
     assert occ_p.shape == true_occ.shape
     # conservative: never marks an occupied tile empty
     assert bool(np.all((true_occ == 0) | (np.asarray(occ_p) > 0)))

@@ -30,8 +30,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.spikes import occupancy_to_csr, tile_occupancy
-from repro.kernels import (apec_kernel, dispatch, lif_scan, sdsa_kernel,
-                           spike_matmul)
+from repro.kernels import (apec_kernel, dispatch, im2col, lif_scan,
+                           sdsa_kernel, spike_matmul)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -129,6 +129,26 @@ CASES = {
         [((SF_BH, 200, SF_WORDS), u32)] * 2),
     "causal_sdsa": (lambda kv: sdsa_kernel.sdsa_causal_status_pallas(
         kv, interpret=False), [((LM_BH, LM_TOKENS, LM_WORDS), u32)]),
+    # econv's patch rows at the benchmark's widths (T*B = 4*128 images):
+    # VGG11's direct-coded 3-channel input, a stride-2 narrow map, and
+    # 2x2 maps of 512 channels
+    "im2col_ci3": (lambda x: im2col.im2col_pallas(
+        x, 3, 3, 1, (32, 32), 128, interpret=False),
+        [((4 * 128, 34, 34, 3), f32)]),
+    "im2col_ci64_stride2": (lambda x: im2col.im2col_pallas(
+        x, 3, 3, 2, (16, 16), 640, interpret=False),
+        [((4 * 128, 34, 34, 64), f32)]),
+    "im2col_ci512_2x2": (lambda x: im2col.im2col_pallas(
+        x, 3, 3, 1, (2, 2), 4608, interpret=False),
+        [((4 * 128, 4, 4, 512), f32)]),
+    # maps too large for one step, cut into bands of output rows: the
+    # DVS128 gesture frames (128x128x2) and a 224x224 stem at stride 2
+    "im2col_ci2_128x128": (lambda x: im2col.im2col_pallas(
+        x, 3, 3, 1, (128, 128), 128, interpret=False),
+        [((4 * 2, 130, 130, 2), f32)]),
+    "im2col_ci3_224x224_stride2": (lambda x: im2col.im2col_pallas(
+        x, 7, 7, 2, (112, 112), 256, interpret=False),
+        [((2, 229, 229, 3), f32)]),
 }
 
 
@@ -147,7 +167,8 @@ KERNEL_NAMES = {
     "lif", "lif_occ", "lif_occ_packed", "grad_lif_fwd", "grad_lif_bwd",
     "grad_lif_occ_fwd", "_spike_matmul_predicated", "_spike_matmul_csr_core",
     "_spike_matmul_packed_csr", "apec_matmul_csr", "apec_matmul_packed_csr",
-    "apec_decompose", "sdsa_status", "sdsa_apply", "causal_sdsa_status"}
+    "apec_decompose", "sdsa_status", "sdsa_apply", "causal_sdsa_status",
+    "im2col"}
 # The registry ops whose Pallas routes run each reader's kernels.
 FAMILY_OPS = {"roofline.event_matmul": ("econv", "spike_matmul"),
               "roofline.lif": ("lif_scan", "lif_scan_occ")}
@@ -252,3 +273,37 @@ def test_bench_program_has_im2col(config, bench_programs):
     assert any(re.search(r"(^|/)im2col/", op.group(1))
                for text in _instructions(hlo)
                for op in [HLO_OP_NAME.search(text)] if op)
+
+
+# An instruction's result shape, where it is one array.
+HLO_SHAPE = re.compile(r"^%[\w.\-]+ = \w+\[([\d,]*)\]")
+
+
+def _conv_weight_shapes(config):
+    """The HWIO shapes of the benchmark model's convolution weights."""
+    base = os.path.join(BENCH, "configs", config)
+    with open(base + ".json") as f:
+        cfg = json.load(f)
+    params = jax.eval_shape(
+        functools.partial(load_module(base + ".py").init, cfg),
+        jax.random.key(0))
+    return {p.shape for p in jax.tree.leaves(params) if p.ndim == 4}
+
+
+@pytest.mark.parametrize("config", sorted(LAYER_SCOPES))
+def test_bench_program_im2col_is_copies(config, bench_programs):
+    """econv's patches are slices of the input map, not a convolution
+    against an identity kernel, and its HWIO weights reach the kernel by
+    a reshape: no weight is relaid to (Ci, kh, kw, Co) on the way. (Where
+    Ci == kh == kw the two orders share a shape, which says nothing.)"""
+    hlo, _ = bench_programs[config]
+    relaid = {(ci, kh, kw, co) for kh, kw, ci, co in
+              _conv_weight_shapes(config)} - _conv_weight_shapes(config)
+    assert relaid
+    for text in _instructions(hlo):
+        op = HLO_OP_NAME.search(text)
+        assert not (" convolution(" in text and op
+                    and re.search(r"(^|/)im2col/", op.group(1))), text
+        shape = HLO_SHAPE.match(text)
+        assert not (shape and tuple(
+            int(d) for d in shape.group(1).split(",") if d) in relaid), text
